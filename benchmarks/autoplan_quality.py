@@ -19,7 +19,7 @@ from benchmarks.common import emit
 from repro.autoplan import LayerwisePlan, SearchConfig, plan_errors, search_plan
 from repro.configs.base import get_config
 from repro.core.transforms import TransformPlan
-from repro.launch import compat
+from repro.launch.mesh import make_test_mesh
 from repro.models.api import get_model
 from repro.serving.fold import collect_calibration
 
@@ -32,9 +32,9 @@ ARCHS = (
 
 def run(keep_samples: int = 128) -> dict:
     key = jax.random.PRNGKey(0)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh()
     out: dict[str, float] = {}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for arch, family in ARCHS:
             cfg = get_config(arch).reduced()
             model = get_model(cfg)

@@ -13,9 +13,9 @@ from benchmarks.common import emit, timeit
 from repro.configs.base import get_config
 from repro.core.qlinear import QuantPolicy
 from repro.core.transforms import TransformPlan
+from repro.launch.mesh import make_test_mesh
 from repro.models.api import get_model
 from repro.serving.fold import collect_calibration, fold_quantize
-from repro.launch import compat
 
 PLANS = {
     "none": TransformPlan(attn_in="none", attn_out="none", mlp_in="none",
@@ -30,9 +30,9 @@ ARCHS = ("stablelm_3b", "mamba2_780m", "deepseek_v2_lite_16b")
 
 def run(auto_plan: bool = False) -> dict:
     key = jax.random.PRNGKey(0)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh()
     out = {}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for arch in ARCHS:
             cfg = get_config(arch).reduced()
             model = get_model(cfg)

@@ -17,18 +17,18 @@ from repro.core.difficulty import (
 )
 from repro.core.transforms import get_transform
 from repro.data import synthetic_batches
+from repro.launch.mesh import make_test_mesh
 from repro.launch.train import make_train_step
 from repro.models.api import get_model
 from repro.optim import adamw
-from repro.launch import compat
 
 KINDS = ("none", "smooth", "rotate", "smooth_rotate")
 
 
 def main():
     key = jax.random.PRNGKey(0)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
+    mesh = make_test_mesh()
+    with jax.set_mesh(mesh):
         cfg = get_config("qwen1.5-4b").reduced(num_layers=4, d_model=128,
                                                d_ff=256, vocab_size=128)
         model = get_model(cfg)

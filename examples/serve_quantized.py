@@ -13,18 +13,18 @@ import numpy as np
 from repro.configs.base import get_config
 from repro.core.qlinear import QuantPolicy
 from repro.data import synthetic_batches
+from repro.launch.mesh import make_test_mesh
 from repro.launch.train import make_train_step
 from repro.models.api import get_model
 from repro.optim import adamw
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.fold import collect_calibration, fold_quantize
-from repro.launch import compat
 
 
 def main():
     key = jax.random.PRNGKey(0)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
+    mesh = make_test_mesh()
+    with jax.set_mesh(mesh):
         cfg = get_config("qwen1.5-4b").reduced(num_layers=2, d_model=128,
                                                vocab_size=256)
         model = get_model(cfg)

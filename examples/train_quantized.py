@@ -21,12 +21,12 @@ from repro.checkpoint import Checkpointer
 from repro.configs.base import get_config
 from repro.core.qlinear import QuantPolicy
 from repro.data import synthetic_batches
+from repro.launch.mesh import make_test_mesh
 from repro.launch.train import make_train_step
 from repro.models.api import get_model
 from repro.optim import adamw, warmup_cosine
 from repro.runtime.fault_tolerance import PreemptionHandler, StragglerPolicy
 from repro.serving.fold import collect_calibration, fold_quantize
-from repro.launch import compat
 
 
 def main(argv=None):
@@ -54,14 +54,14 @@ def main(argv=None):
           f"V={cfg.vocab_size}  (~{n_params_est/1e6:.1f}M params)")
 
     key = jax.random.PRNGKey(0)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh()
     model = get_model(cfg)
     opt = adamw(warmup_cosine(3e-3, 20, steps))
     preempt = PreemptionHandler()
     straggler = StragglerPolicy()
     ckpt = Checkpointer(args.ckpt, keep=2)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(key, cfg)
         state = opt.init(params)
         start = 0

@@ -27,7 +27,6 @@ from repro.checkpoint import Checkpointer
 from repro.configs.base import get_config
 from repro.core.transforms import TransformPlan
 from repro.data import calibration_stream
-from repro.launch import compat
 from repro.launch.mesh import make_test_mesh
 from repro.models.api import get_model
 from repro.serving.fold import collect_calibration
@@ -69,7 +68,7 @@ def main(argv=None):
         alpha_grid=alpha_grid, top_k=args.top_k,
         weight_bits=args.weight_bits, act_bits=args.act_bits)
 
-    with compat.set_mesh(make_test_mesh()):
+    with jax.set_mesh(make_test_mesh()):
         params = model.init(jax.random.PRNGKey(0), cfg)
         if args.checkpoint:
             restored = Checkpointer(args.checkpoint).restore_latest({"p": params})

@@ -16,8 +16,10 @@ whole chain into ONE ``pallas_call``:
     block — its BlockSpec index is constant over the m/k grid axes, so
     the pipeline never refetches it;
   * smooth-divide, the trailing power-of-two Hadamard factor H_b (held
-    in VMEM, applied as an MXU matmul over contiguous b-groups exactly
-    like ``fused_hadamard_quant``), and the per-token absmax quantize
+    in VMEM, applied as an MXU matmul over contiguous b-groups; a factor
+    under 128 — down_proj's 6912 = Paley_108 ⊗ H_64 — is applied as
+    kron(I_{128/b}, H_b) on 128-lane groups, which keeps every row a
+    whole vreg wide), and the per-token absmax quantize
     run on the first visit of each row block, writing int8 codes and
     f32 scales into VMEM *scratch* — never to HBM;
   * a traced ``had_mask`` scalar gates the rotation IN-KERNEL, so mixed
@@ -68,7 +70,7 @@ __all__ = ["fused_qlinear"]
 _pallas_call = pl.pallas_call
 
 
-def _kernel(*refs, k_steps: int, levels: int, block: int, block_k: int,
+def _kernel(*refs, k_steps: int, levels: int, group: int, block_k: int,
             packed: bool, has_smooth: bool, has_had: bool, has_mask: bool):
     it = iter(refs)
     x_ref = next(it)
@@ -88,8 +90,9 @@ def _kernel(*refs, k_steps: int, levels: int, block: int, block_k: int,
         if has_smooth:
             x = x / s_ref[...]                          # (1, k) broadcast
         if has_had:
+            # H_b acts on ``group``-lane rows (kron(I, H_b) when b < 128)
             bn, k = x.shape
-            xr = x.reshape(bn * (k // block), block)
+            xr = x.reshape(bn * (k // group), group)
             xt = jax.lax.dot_general(
                 xr, h_ref[...], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -165,13 +168,15 @@ def fused_qlinear(x: jax.Array, qw: QuantizedWeight, *, act_bits: int = 4,
     levels = qmax(act_bits)
 
     # --- tiling: pad to tile boundaries instead of degenerate divisors ---
-    unit = max(block, 128) if has_had else 128  # block | unit (powers of 2)
+    # the rotation works on rows of ``group`` lanes: H_b itself, or the
+    # block-diagonal kron(I_{128/b}, H_b) for factors under a vreg width
+    group = max(block, 128)                     # block | group (powers of 2)
     bn = min(block_n, _round_up(n, 8))
     bm = min(block_m, _round_up(m_ := qw.c_out, 128))
-    # bk must stay a multiple of unit: Hadamard groups may not straddle
+    # bk must stay a multiple of group: Hadamard groups may not straddle
     # the padded region, and packed nibble pairs may not straddle blocks
-    # (unit is even) — guards caller-overridden odd/unaligned block_k
-    bk = _round_up(min(block_k, _round_up(k, unit)), unit)
+    # (group is even) — guards caller-overridden odd/unaligned block_k
+    bk = _round_up(min(block_k, _round_up(k, group)), group)
     n_p, m_p, k_p = _round_up(n, bn), _round_up(m_, bm), _round_up(k, bk)
 
     x_p = jnp.pad(x, ((0, n_p - n), (0, k_p - k)))
@@ -187,8 +192,8 @@ def fused_qlinear(x: jax.Array, qw: QuantizedWeight, *, act_bits: int = 4,
         inputs.append(s_p)
         in_specs.append(pl.BlockSpec((1, k_p), lambda i, j, kk: (0, 0)))
     if has_had:
-        inputs.append(vmem_rotation_factor(block))
-        in_specs.append(pl.BlockSpec((block, block), lambda i, j, kk: (0, 0)))
+        inputs.append(vmem_rotation_factor(block, group))
+        in_specs.append(pl.BlockSpec((group, group), lambda i, j, kk: (0, 0)))
     if has_mask:
         inputs.append(jnp.asarray(had_mask, jnp.float32).reshape(1, 1))
         in_specs.append(pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)))
@@ -199,7 +204,7 @@ def fused_qlinear(x: jax.Array, qw: QuantizedWeight, *, act_bits: int = 4,
 
     y = _pallas_call(
         functools.partial(
-            _kernel, k_steps=k_p // bk, levels=levels, block=block,
+            _kernel, k_steps=k_p // bk, levels=levels, group=group,
             block_k=bk, packed=qw.packed, has_smooth=has_smooth,
             has_had=has_had, has_mask=has_mask),
         grid=(n_p // bn, m_p // bm, k_p // bk),

@@ -21,6 +21,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.hadamard import sylvester
@@ -29,10 +30,19 @@ from repro.core.quantizer import qmax
 __all__ = ["fused_hadamard_quant", "vmem_rotation_factor"]
 
 
-def vmem_rotation_factor(block: int) -> jax.Array:
+def vmem_rotation_factor(block: int, lanes: int | None = None) -> jax.Array:
     """H_block/√block as f32 — the VMEM-resident trailing rotation factor
-    shared by this kernel and the one-pass ``fused_qlinear``."""
-    return jnp.asarray(sylvester(block).astype("float32") / math.sqrt(block))
+    shared by this kernel and the one-pass ``fused_qlinear``.
+
+    ``lanes`` (a multiple of ``block``) returns the block-diagonal
+    kron(I_{lanes/block}, H_block/√block) instead: the same rotation on
+    ``lanes``-wide groups, so a kernel whose factor is narrower than the
+    128-lane vreg keeps its rows 128 lanes wide (Mosaic rejects the
+    lane-changing reshape to ``block`` columns)."""
+    h = sylvester(block).astype(np.float32) / math.sqrt(block)
+    if lanes is not None and lanes != block:
+        h = np.kron(np.eye(lanes // block, dtype=np.float32), h)
+    return jnp.asarray(h)
 
 
 def _fhq_kernel(x_ref, h_ref, q_ref, s_ref, *, levels: int, block: int):

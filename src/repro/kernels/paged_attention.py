@@ -72,7 +72,7 @@ def _kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, *refs, page: int,
     else:
         o_ref, m_ref, l_ref, acc_ref = refs
         ks_ref = vs_ref = None
-    i, j = pl.program_id(0), pl.program_id(2)
+    i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -87,38 +87,36 @@ def _kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, *refs, page: int,
 
     @pl.when(live)
     def _page_step():
-        # one logical page of this (slot, kv-head): blocks were DMA'd by
-        # the table-driven index maps, so k/v arrive already "gathered"
-        k = k_ref[0, :, 0, :]                      # (page, d)
-        v = v_ref[0, :, 0, :]
+        # one logical page of this slot, every kv head: blocks were DMA'd
+        # by the table-driven index maps, so k/v arrive already "gathered"
+        k = k_ref[0]                               # (page, hkv, d)
+        v = v_ref[0]
         if quantized:
             # in-kernel dequant from the paged scale leaves — identical
             # staging to paged_view: (int8 · f32 scale) → bf16 → f32
-            k = (k.astype(jnp.float32) * ks_ref[0, :, 0, :]
-                 ).astype(jnp.bfloat16)
-            v = (v.astype(jnp.float32) * vs_ref[0, :, 0, :]
-                 ).astype(jnp.bfloat16)
-        q = q_ref[0, 0].astype(jnp.float32)        # (g, d)
+            k = (k.astype(jnp.float32) * ks_ref[0]).astype(jnp.bfloat16)
+            v = (v.astype(jnp.float32) * vs_ref[0]).astype(jnp.bfloat16)
+        q = q_ref[0].astype(jnp.float32)           # (hkv, g, d)
         d = q.shape[-1]
-        s = jax.lax.dot_general(
-            q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * (d ** -0.5)   # (g, page)
-        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # the GQA groups reduce against their kv head in one batched dot
+        s = jnp.einsum("hgd,phd->hgp", q, k.astype(jnp.float32),
+                       preferred_element_type=jnp.float32) * (d ** -0.5)
+        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(pos < valid, s, -1e30)
         # online-softmax update (running max / sum / weighted accumulator)
         m_new = jnp.maximum(m_ref[...], s.max(-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_ref[...] - m_new)
         l_ref[...] = l_ref[...] * corr + p.sum(-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
+            "hgp,phd->hgd", p, v.astype(jnp.float32),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _epilogue():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, layer_kv: dict, page_table: jax.Array,
@@ -154,33 +152,35 @@ def paged_attention(q: jax.Array, layer_kv: dict, page_table: jax.Array,
     lens = jnp.broadcast_to(
         jnp.asarray(lengths, jnp.int32).reshape(-1), (b,))
 
-    def page_map(i, h, j, t, ln):
+    def page_map(i, j, t, ln):
         # the table drives the DMA: physical page of logical page j;
         # dead entries (-1) clamp to page 0 — fetched but never read
         # (the pl.when(live) gate skips the body)
-        return (jnp.maximum(t[i, j], 0), 0, h, 0)
+        return (jnp.maximum(t[i, j], 0), 0, 0, 0)
 
+    # a page block spans every kv head: the pool's trailing (hkv, d) dims
+    # are then whole, which is what the TPU block tiling accepts
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda i, h, j, t, ln: (i, h, 0, 0)),
-        pl.BlockSpec((1, page, 1, d), page_map),
-        pl.BlockSpec((1, page, 1, d), page_map),
+        pl.BlockSpec((1, hkv, g, d), lambda i, j, t, ln: (i, 0, 0, 0)),
+        pl.BlockSpec((1, page, hkv, d), page_map),
+        pl.BlockSpec((1, page, hkv, d), page_map),
     ]
     inputs = [qg, kp, vp]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page, 1, 1), page_map),
-                     pl.BlockSpec((1, page, 1, 1), page_map)]
+        in_specs += [pl.BlockSpec((1, page, hkv, 1), page_map),
+                     pl.BlockSpec((1, page, hkv, 1), page_map)]
         inputs += [layer_kv["k_scale"], layer_kv["v_scale"]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, width),
+        grid=(b, width),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda i, h, j, t, ln: (i, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, hkv, g, d),
+                               lambda i, j, t, ln: (i, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),   # running max m
-            pltpu.VMEM((g, 1), jnp.float32),   # running sum l
-            pltpu.VMEM((g, d), jnp.float32),   # weighted accumulator
+            pltpu.VMEM((hkv, g, 1), jnp.float32),   # running max m
+            pltpu.VMEM((hkv, g, 1), jnp.float32),   # running sum l
+            pltpu.VMEM((hkv, g, d), jnp.float32),   # weighted accumulator
         ],
     )
     out = _pallas_call(

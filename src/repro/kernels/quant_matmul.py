@@ -71,11 +71,14 @@ def _qmm_kernel(aq_ref, wq_ref, as_ref, ws_ref, o_ref, acc_ref, *, k_steps: int)
 
 
 def _unpack_nibbles(packed: jax.Array) -> jax.Array:
-    """(bk/2, bm) int8 bytes → (bk, bm) int8 codes, pairs along axis 0."""
-    lo = jnp.right_shift(jnp.left_shift(packed, 4), 4)  # sign-extended low
-    hi = jnp.right_shift(packed, 4)
+    """(bk/2, bm) int8 bytes → (bk, bm) int8 codes, pairs along axis 0.
+
+    The shifts run on int32: Mosaic cannot legalize an int8 ``shli``."""
+    w = packed.astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(w, 28), 28)  # sign-extended low
+    hi = jnp.right_shift(w, 4)
     bk2, bm = packed.shape
-    return jnp.stack([lo, hi], axis=1).reshape(bk2 * 2, bm)
+    return jnp.stack([lo, hi], axis=1).reshape(bk2 * 2, bm).astype(jnp.int8)
 
 
 def _qmm_packed_kernel(aq_ref, wq_ref, as_ref, ws_ref, o_ref, acc_ref, *, k_steps: int):

@@ -42,7 +42,6 @@ from repro.launch.sharding import batch_spec, cache_specs, param_specs
 from repro.models.api import get_model
 from repro.optim import adamw
 from repro.serving.fold import fold_quantize
-from repro.launch import compat
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "experiments", "dryrun")
@@ -218,14 +217,14 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str, *,
     set_strategy(strategy)
     cell = SHAPES[shape_name]
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, args, shardings, note, cfg = build_cell(
             arch, cell, mesh, quantized=quantized, microbatches=microbatches,
             opt=opt)
         if strategy != "2d":
             note += f" strategy={strategy}"
         donate = (0, 1) if cell.kind == "train" else (2,)
-        lowered = jax.jit(fn, in_shardings=compat.jit_shardings(mesh, shardings),
+        lowered = jax.jit(fn, in_shardings=shardings,
                           donate_argnums=donate).lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

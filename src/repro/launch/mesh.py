@@ -6,9 +6,21 @@ state (jax locks the device count on first backend init).
 
 from __future__ import annotations
 
-from repro.launch import compat
+from typing import Sequence
 
-__all__ = ["make_production_mesh", "make_test_mesh", "dp_axes", "dp_size"]
+import jax
+
+__all__ = ["make_mesh", "make_production_mesh", "make_test_mesh", "dp_axes",
+           "dp_size"]
+
+
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
+    """``jax.make_mesh`` with Auto axis types: the sharding rules are
+    partition-spec annotations that GSPMD propagates (jax's own default
+    is Explicit)."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,12 +31,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 1, model: int = 1):
     """Small mesh for CPU tests (same axis names as production)."""
-    return compat.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

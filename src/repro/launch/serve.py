@@ -27,6 +27,11 @@ prints the same reports from the same trace schema (docs/serving.md).
 On a real cluster this runs under the production mesh with the sharding
 rules from launch/sharding.py; the CPU path uses a (1,1) mesh with the
 same code.
+
+``main`` is ``run(args, *build(args))`` under that mesh: ``build`` makes
+the (folded) params and ``run`` serves them and returns the report dict
+(``chip_smoke.py`` calls the two itself to check the folded params
+further on the chip).
 """
 
 from __future__ import annotations
@@ -35,6 +40,7 @@ import argparse
 import json
 import os
 import time
+from typing import NamedTuple
 
 import jax
 import numpy as np
@@ -44,7 +50,7 @@ from repro.configs.base import get_config
 from repro.core.qlinear import QuantPolicy
 from repro.core.transforms import TransformPlan
 from repro.data import calibration_stream
-from repro.launch import compat
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models.api import get_model
 from repro.obs import Observability, QuantHealthSampler, format_summary
@@ -54,7 +60,17 @@ from repro.serving.engine import (EngineConfig, PagedServingEngine,
 from repro.serving.fold import collect_calibration, fold_quantize
 
 
-def main(argv=None):
+class Built(NamedTuple):
+    """What ``build`` hands to ``run``."""
+
+    cfg: object
+    model: object
+    params: dict
+    policy: QuantPolicy | None
+    stats: dict | None
+
+
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-3b")
     ap.add_argument("--reduced", action="store_true")
@@ -155,213 +171,241 @@ def main(argv=None):
     ap.add_argument("--json", action="store_true",
                     help="emit ONE structured JSON report on stdout "
                          "instead of the human tables")
-    args = ap.parse_args(argv)
-    say = (lambda *a, **k: None) if args.json else print
+    return ap
 
+
+def parse_args(argv=None) -> argparse.Namespace:
+    return _parser().parse_args(argv)
+
+
+def _say(args):
+    return (lambda *a, **k: None) if args.json else print
+
+
+def build(args: argparse.Namespace) -> Built:
+    """Init (or restore) the params; unless ``--no-quant``, calibrate and
+    fold them per the plan into the quantized serving params."""
+    say = _say(args)
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     model = get_model(cfg)
-    mesh = make_test_mesh()
     key = jax.random.PRNGKey(0)
+    params = model.init(key, cfg)
+    if args.checkpoint:
+        ck = Checkpointer(args.checkpoint)
+        restored = ck.restore_latest({"p": params})
+        if restored:
+            params = restored[0]["p"]
+            say(f"restored checkpoint step {restored[1]}")
 
-    with compat.set_mesh(mesh):
-        params = model.init(key, cfg)
-        if args.checkpoint:
-            ck = Checkpointer(args.checkpoint)
-            restored = ck.restore_latest({"p": params})
-            if restored:
-                params = restored[0]["p"]
-                say(f"restored checkpoint step {restored[1]}")
+    policy = stats = None
+    if not args.no_quant:
+        t0 = time.time()
+        stats = collect_calibration(
+            model, params, cfg,
+            list(calibration_stream(cfg, n_batches=2, batch=2, seq=64)),
+            keep_samples=128 if args.auto_plan else 0)
+        policy = QuantPolicy(
+            weight_bits=args.weight_bits, act_bits=args.act_bits,
+            kv_cache_bits=args.kv_bits or None,
+            use_kernels=args.use_kernels)
+        if args.auto_plan:
+            from repro.autoplan import SearchConfig, search_plan
 
-        policy = None
-        if not args.no_quant:
-            t0 = time.time()
-            stats = collect_calibration(
-                model, params, cfg,
-                list(calibration_stream(cfg, n_batches=2, batch=2, seq=64)),
-                keep_samples=128 if args.auto_plan else 0)
-            policy = QuantPolicy(
-                weight_bits=args.weight_bits, act_bits=args.act_bits,
-                kv_cache_bits=args.kv_bits or None,
-                use_kernels=args.use_kernels)
-            if args.auto_plan:
-                from repro.autoplan import SearchConfig, search_plan
+            plan, _ = search_plan(
+                params, cfg, stats,
+                search=SearchConfig(weight_bits=args.weight_bits,
+                                    act_bits=args.act_bits),
+                base=TransformPlan(alpha=args.alpha))
+            plan_desc = "searched per-layer plan (repro.autoplan)"
+        elif args.plan_json:
+            from repro.autoplan import LayerwisePlan
 
-                plan, _ = search_plan(
-                    params, cfg, stats,
-                    search=SearchConfig(weight_bits=args.weight_bits,
-                                        act_bits=args.act_bits),
-                    base=TransformPlan(alpha=args.alpha))
-                plan_desc = "searched per-layer plan (repro.autoplan)"
-            elif args.plan_json:
-                from repro.autoplan import LayerwisePlan
-
-                plan = LayerwisePlan.load(args.plan_json)
-                # a mismatched plan would silently fall back to its base
-                # for every stack — fail loudly instead (the planned stack
-                # excludes MoE leading dense layers)
-                planned_stack = cfg.num_layers - cfg.first_dense_layers
-                if plan.num_layers != planned_stack:
-                    ap.error(f"{args.plan_json} plans {plan.num_layers} "
-                             f"layers but {cfg.name}'s planned stack has "
-                             f"{planned_stack} — searched on a different "
-                             "config?")
-                if plan.arch and plan.arch != cfg.name:
-                    say(f"WARNING: plan searched on {plan.arch!r}, "
-                          f"serving {cfg.name!r}")
-                plan_desc = f"LayerwisePlan from {args.plan_json}"
-            else:
-                plan = TransformPlan(alpha=args.alpha)
-                plan_desc = "SmoothRotation on down_proj — paper §V"
-            params = fold_quantize(params, cfg, policy=policy, plan=plan,
-                                   stats=stats)
-            say(f"calibrated + folded W{args.weight_bits}A{args.act_bits} "
-                  f"in {time.time() - t0:.1f}s (plan: {plan_desc})")
-
-        qh = None
-        if args.quant_health:
-            qh = QuantHealthSampler(
-                model, params, cfg, policy=policy, every=args.quant_health,
-                reference=stats if not args.no_quant else None,
-                max_context=args.max_len)
-        obs = Observability(trace_path=args.trace_out or None,
-                            quant_health=qh)
-        faults = None
-        if args.fault_plan:
-            from repro.resilience.faults import FaultPlan
-
-            text = args.fault_plan
-            if os.path.exists(text):
-                with open(text) as fh:
-                    text = fh.read()
-            faults = FaultPlan.from_json(text)
-            say(f"fault plan armed: {faults}")
-        # ONE EngineConfig carries every engine knob (docs/api.md); the
-        # non-paged engines ignore the page-pool fields
-        econfig = EngineConfig(
-            max_slots=args.max_slots, max_len=args.max_len, policy=policy,
-            kv_bits=args.kv_bits or None, page_size=args.page_size,
-            n_pages=args.pool_pages or None,
-            prefill_chunk=args.prefill_chunk or None, obs=obs,
-            faults=faults, nan_guard=args.nan_guard,
-            prefix_cache=args.prefix_cache, spec_k=args.speculate)
-        engine_cls = {"paged": PagedServingEngine, "batched": ServingEngine,
-                      "per-slot": PerSlotServingEngine}[args.engine]
-        eng = engine_cls(model, params, cfg, config=econfig)
-        rng = np.random.default_rng(0)
-        prompts = [rng.integers(0, cfg.vocab_size, size=(4 + i % 13,))
-                   for i in range(args.requests)]
-        if args.prefix_cache:
-            # the workload shape the cache exists for: every request
-            # opens with the same "system prompt", unique tail per user;
-            # whole pages, capped so prompt + tail + decode fit max_len
-            max_tail = max(len(p) for p in prompts)
-            headroom = args.max_len - max_tail - args.max_new
-            sys_len = max(args.page_size,
-                          min(4, headroom // args.page_size) * args.page_size)
-            system = rng.integers(0, cfg.vocab_size, size=(sys_len,))
-            prompts = [np.concatenate([system, p]) for p in prompts]
-        if args.serve_http:
-            import asyncio
-
-            from repro.serving.frontend import ServingFrontend, http_generate
-
-            async def _drive():
-                fe = ServingFrontend(
-                    eng, port=args.http_port,
-                    max_queue_depth=args.shed_queue_depth,
-                    shed_score=args.shed_score,
-                    default_deadline_s=args.deadline_s or None)
-                async with fe:
-                    say(f"HTTP front-end on {fe.host}:{fe.port}")
-                    return await asyncio.gather(*[
-                        http_generate(fe.host, fe.port, {
-                            "prompt": p.tolist(),
-                            "max_new_tokens": args.max_new,
-                            "temperature": args.temperature})
-                        for p in prompts])
-
-            t0 = time.time()
-            results = asyncio.run(_drive())
-            dt = time.time() - t0
-            done = [Request(uid=r["body"]["uid"], prompt=prompts[i],
-                            out_tokens=r["body"]["tokens"],
-                            done=True, cancelled=r["body"]["cancelled"])
-                    for i, r in enumerate(results) if r["status"] == 200]
-            eng.run_stats = st = eng.stats()
+            plan = LayerwisePlan.load(args.plan_json)
+            # a mismatched plan would silently fall back to its base
+            # for every stack — fail loudly instead (the planned stack
+            # excludes MoE leading dense layers)
+            planned_stack = cfg.num_layers - cfg.first_dense_layers
+            if plan.num_layers != planned_stack:
+                _parser().error(f"{args.plan_json} plans {plan.num_layers} "
+                                f"layers but {cfg.name}'s planned stack "
+                                f"has {planned_stack} — searched on a "
+                                "different config?")
+            if plan.arch and plan.arch != cfg.name:
+                say(f"WARNING: plan searched on {plan.arch!r}, "
+                    f"serving {cfg.name!r}")
+            plan_desc = f"LayerwisePlan from {args.plan_json}"
         else:
-            for i, p in enumerate(prompts):
-                eng.submit(Request(uid=i, prompt=p,
-                                   max_new_tokens=args.max_new,
-                                   temperature=args.temperature))
-            t0 = time.time()
-            done = eng.run(max_ticks=10_000)
-            dt = time.time() - t0
-            st = eng.run_stats
-        obs.close()
-        if args.metrics_out:
-            with open(args.metrics_out, "w") as fh:
-                json.dump(obs.registry.snapshot(), fh, indent=1,
-                          sort_keys=True)
-        from repro.kernels import ops
+            plan = TransformPlan(alpha=args.alpha)
+            plan_desc = "SmoothRotation on down_proj — paper §V"
+        params = fold_quantize(params, cfg, policy=policy, plan=plan,
+                               stats=stats)
+        say(f"calibrated + folded W{args.weight_bits}A{args.act_bits} "
+            f"in {time.time() - t0:.1f}s (plan: {plan_desc})")
+    return Built(cfg, model, params, policy, stats)
 
-        summary = obs.summary()
-        if args.json:
-            # one machine-readable document: run stats (minus the bulky
-            # per-request map), the obs latency summary, and the
-            # process-wide dispatch-resolution tally
-            report = {
-                "arch": cfg.name, "engine": args.engine,
-                "requests_served": len(done),
-                "wall_s": dt,
-                "decode_tok_per_s": st["decode_tokens"] / max(dt, 1e-9),
-                "run_stats": {k: v for k, v in st.items()
-                              if k != "per_request"},
-                "obs": summary,
-                "dispatch_resolutions": ops.dispatch_resolutions(),
-            }
-            print(json.dumps(report, indent=1, sort_keys=True))
-            return
-        print(f"served {len(done)}/{args.requests} requests, "
-              f"{st['decode_tokens']} tokens in {dt:.2f}s "
-              f"({st['decode_tokens'] / max(dt, 1e-9):.1f} tok/s, "
-              f"{args.engine} engine: {st['decode_dispatches']} decode "
-              f"dispatches over {st['ticks']} ticks = "
-              f"{st['dispatches_per_tick']:.2f}/tick, plus "
-              f"{st['prefill_dispatches']} prefill dispatches, "
-              f"kernel backend: {eng.kernel_backend})")
-        if "n_pages" in st:
-            print(f"  page pool: {st['peak_pages_in_use']}/{st['n_pages']} "
-                  f"pages at peak ({100 * st['page_occupancy_peak']:.0f}% "
-                  f"occupancy, page size {st['page_size']}), "
-                  f"paged attention: {st['paged_attention_backend']}")
-        if st.get("prefix", {}).get("enabled"):
-            px = st["prefix"]
-            print(f"  prefix cache: {px['hits']}/{px['hits'] + px['misses']} "
-                  f"hits ({100 * px['hit_rate']:.0f}%), "
-                  f"{px['shared_pages']} shared pages, "
-                  f"{px['saved_prefill_tokens']} prefill tokens saved, "
-                  f"{px['cow_copies']} COW copies, "
-                  f"{px['evictions']} evictions")
-        if st.get("spec", {}).get("enabled"):
-            sp = st["spec"]
-            print(f"  speculative (k={sp['k']}, "
-                  f"{'self' if sp['self_draft'] else 'separate'}-draft): "
-                  f"{sp['accepted']}/{sp['drafted']} drafts accepted "
-                  f"({100 * sp['acceptance_rate']:.0f}%), "
-                  f"{sp['emitted_tokens']} tokens over "
-                  f"{sp['verify_dispatches']} verify dispatches = "
-                  f"{sp['accepted_per_dispatch']:.2f} tokens/dispatch")
-        for r in done[:3]:
-            print(f"  req {r.uid}: {r.out_tokens[:12]}...")
-        print()
-        print(format_summary(summary))
-        print(f"backend resolutions (kernels.ops): "
-              f"{ops.dispatch_resolutions()}")
-        if args.trace_out:
-            print(f"trace: {args.trace_out} "
-                  f"(summarize: python -m repro.obs {args.trace_out})")
+
+def run(args: argparse.Namespace, cfg, model, params, policy, stats) -> dict:
+    """Serve the request stream on ``params``; print the report (human
+    tables, or ONE JSON document with ``--json``) and return it."""
+    say = _say(args)
+    qh = None
+    if args.quant_health:
+        qh = QuantHealthSampler(
+            model, params, cfg, policy=policy, every=args.quant_health,
+            reference=stats if not args.no_quant else None,
+            max_context=args.max_len)
+    obs = Observability(trace_path=args.trace_out or None,
+                        quant_health=qh)
+    faults = None
+    if args.fault_plan:
+        from repro.resilience.faults import FaultPlan
+
+        text = args.fault_plan
+        if os.path.exists(text):
+            with open(text) as fh:
+                text = fh.read()
+        faults = FaultPlan.from_json(text)
+        say(f"fault plan armed: {faults}")
+    # ONE EngineConfig carries every engine knob (docs/api.md); the
+    # non-paged engines ignore the page-pool fields
+    econfig = EngineConfig(
+        max_slots=args.max_slots, max_len=args.max_len, policy=policy,
+        kv_bits=args.kv_bits or None, page_size=args.page_size,
+        n_pages=args.pool_pages or None,
+        prefill_chunk=args.prefill_chunk or None, obs=obs,
+        faults=faults, nan_guard=args.nan_guard,
+        prefix_cache=args.prefix_cache, spec_k=args.speculate)
+    engine_cls = {"paged": PagedServingEngine, "batched": ServingEngine,
+                  "per-slot": PerSlotServingEngine}[args.engine]
+    eng = engine_cls(model, params, cfg, config=econfig)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, cfg.vocab_size, size=(4 + i % 13,))
+               for i in range(args.requests)]
+    if args.prefix_cache:
+        # the workload shape the cache exists for: every request
+        # opens with the same "system prompt", unique tail per user;
+        # whole pages, capped so prompt + tail + decode fit max_len
+        max_tail = max(len(p) for p in prompts)
+        headroom = args.max_len - max_tail - args.max_new
+        sys_len = max(args.page_size,
+                      min(4, headroom // args.page_size) * args.page_size)
+        system = rng.integers(0, cfg.vocab_size, size=(sys_len,))
+        prompts = [np.concatenate([system, p]) for p in prompts]
+    if args.serve_http:
+        import asyncio
+
+        from repro.serving.frontend import ServingFrontend, http_generate
+
+        async def _drive():
+            fe = ServingFrontend(
+                eng, port=args.http_port,
+                max_queue_depth=args.shed_queue_depth,
+                shed_score=args.shed_score,
+                default_deadline_s=args.deadline_s or None)
+            async with fe:
+                say(f"HTTP front-end on {fe.host}:{fe.port}")
+                return await asyncio.gather(*[
+                    http_generate(fe.host, fe.port, {
+                        "prompt": p.tolist(),
+                        "max_new_tokens": args.max_new,
+                        "temperature": args.temperature})
+                    for p in prompts])
+
+        t0 = time.time()
+        results = asyncio.run(_drive())
+        dt = time.time() - t0
+        done = [Request(uid=r["body"]["uid"], prompt=prompts[i],
+                        out_tokens=r["body"]["tokens"],
+                        done=True, cancelled=r["body"]["cancelled"])
+                for i, r in enumerate(results) if r["status"] == 200]
+        eng.run_stats = st = eng.stats()
+    else:
+        for i, p in enumerate(prompts):
+            eng.submit(Request(uid=i, prompt=p,
+                               max_new_tokens=args.max_new,
+                               temperature=args.temperature))
+        t0 = time.time()
+        done = eng.run(max_ticks=10_000)
+        dt = time.time() - t0
+        st = eng.run_stats
+    obs.close()
+    if args.metrics_out:
+        with open(args.metrics_out, "w") as fh:
+            json.dump(obs.registry.snapshot(), fh, indent=1,
+                      sort_keys=True)
+    from repro.kernels import ops
+
+    summary = obs.summary()
+    dev = jax.devices()[0]
+    # one machine-readable document: run stats (minus the bulky
+    # per-request map), each request's prompt and output tokens, the obs
+    # latency summary, and the process-wide dispatch-resolution tally
+    report = {
+        "arch": cfg.name, "engine": args.engine,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "requests_served": len(done),
+        "requests": [{"uid": r.uid, "prompt": [int(t) for t in r.prompt],
+                      "tokens": [int(t) for t in r.out_tokens],
+                      "failed": r.failed} for r in done],
+        "wall_s": dt,
+        "decode_tok_per_s": st["decode_tokens"] / max(dt, 1e-9),
+        "run_stats": {k: v for k, v in st.items() if k != "per_request"},
+        "obs": summary,
+        "dispatch_resolutions": ops.dispatch_resolutions(),
+    }
+    if args.json:
+        print(json.dumps(report, indent=1, sort_keys=True))
+        return report
+    print(f"served {len(done)}/{args.requests} requests, "
+          f"{st['decode_tokens']} tokens in {dt:.2f}s "
+          f"({st['decode_tokens'] / max(dt, 1e-9):.1f} tok/s, "
+          f"{args.engine} engine: {st['decode_dispatches']} decode "
+          f"dispatches over {st['ticks']} ticks = "
+          f"{st['dispatches_per_tick']:.2f}/tick, plus "
+          f"{st['prefill_dispatches']} prefill dispatches, "
+          f"kernel backend: {eng.kernel_backend})")
+    if "n_pages" in st:
+        print(f"  page pool: {st['peak_pages_in_use']}/{st['n_pages']} "
+              f"pages at peak ({100 * st['page_occupancy_peak']:.0f}% "
+              f"occupancy, page size {st['page_size']}), "
+              f"paged attention: {st['paged_attention_backend']}")
+    if st.get("prefix", {}).get("enabled"):
+        px = st["prefix"]
+        print(f"  prefix cache: {px['hits']}/{px['hits'] + px['misses']} "
+              f"hits ({100 * px['hit_rate']:.0f}%), "
+              f"{px['shared_pages']} shared pages, "
+              f"{px['saved_prefill_tokens']} prefill tokens saved, "
+              f"{px['cow_copies']} COW copies, "
+              f"{px['evictions']} evictions")
+    if st.get("spec", {}).get("enabled"):
+        sp = st["spec"]
+        print(f"  speculative (k={sp['k']}, "
+              f"{'self' if sp['self_draft'] else 'separate'}-draft): "
+              f"{sp['accepted']}/{sp['drafted']} drafts accepted "
+              f"({100 * sp['acceptance_rate']:.0f}%), "
+              f"{sp['emitted_tokens']} tokens over "
+              f"{sp['verify_dispatches']} verify dispatches = "
+              f"{sp['accepted_per_dispatch']:.2f} tokens/dispatch")
+    for r in done[:3]:
+        print(f"  req {r.uid}: {r.out_tokens[:12]}...")
+    print()
+    print(format_summary(summary))
+    print(f"backend resolutions (kernels.ops): "
+          f"{ops.dispatch_resolutions()}")
+    if args.trace_out:
+        print(f"trace: {args.trace_out} "
+              f"(summarize: python -m repro.obs {args.trace_out})")
+    return report
+
+
+def main(argv=None) -> dict:
+    args = parse_args(argv)
+    enable_compile_cache()
+    with jax.set_mesh(make_test_mesh()):
+        return run(args, *build(args))
 
 
 if __name__ == "__main__":

@@ -24,11 +24,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.launch.sharding import batch_spec, param_specs
 from repro.models.api import get_model
 from repro.optim import adamw, apply_error_feedback, warmup_cosine
-from repro.launch import compat
 
 # XLA flags a production TPU launcher sets for compute/comm overlap; they
 # are inert on CPU and applied by the cluster launcher environment.
@@ -106,13 +106,11 @@ def shard_train_fns(model, cfg: ModelConfig, opt, mesh, global_batch: int,
                               grad_compression=grad_compression)
     train_step = jax.jit(
         step_fn,
-        in_shardings=compat.jit_shardings(
-            mesh, (pspecs, ospecs, bspecs, P(), P())),
-        out_shardings=compat.jit_shardings(mesh, (pspecs, ospecs, P())),
+        in_shardings=(pspecs, ospecs, bspecs, P(), P()),
+        out_shardings=(pspecs, ospecs, P()),
         donate_argnums=(0, 1),
     )
-    init_fn = jax.jit(init_all,
-                      out_shardings=compat.jit_shardings(mesh, (pspecs, ospecs)))
+    init_fn = jax.jit(init_all, out_shardings=(pspecs, ospecs))
     return init_fn, train_step, (pspecs, ospecs, bspecs)
 
 
@@ -131,6 +129,7 @@ def main(argv=None):
     ap.add_argument("--save-every", type=int, default=50)
     ap.add_argument("--data", default="synthetic")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -142,7 +141,7 @@ def main(argv=None):
 
     from repro.data.pipeline import synthetic_batches
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         init_fn, train_step, _ = shard_train_fns(
             model, cfg, opt, mesh, args.batch, args.seq,
             microbatches=args.microbatches,

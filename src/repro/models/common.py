@@ -22,7 +22,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.qlinear import QuantPolicy, qlinear
-from repro.launch import compat
 
 Params = dict[str, Any]
 
@@ -56,8 +55,8 @@ def shard_act(x: jax.Array, *, sp: bool = False) -> jax.Array:
     replaces each TP all-reduce with a reduce-scatter + all-gather pair
     at half the wire bytes, and layer-boundary residuals shrink 16×.
     """
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names or mesh.size == 1:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names or mesh.size == 1:
         return x
     from jax.sharding import PartitionSpec as P
 
@@ -659,7 +658,7 @@ def flash_decode(q, layer_kv: dict, valid, *, dp_spec) -> jax.Array:
     """
     from jax.sharding import PartitionSpec as P
 
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     b, sq, hq, d = q.shape
     S = layer_kv["k"].shape[1]
     quantized = layer_kv.get("k_scale") is not None
@@ -700,11 +699,11 @@ def flash_decode(q, layer_kv: dict, valid, *, dp_spec) -> jax.Array:
     scale_spec = kv_spec if quantized else P()
     valid_vec = jnp.broadcast_to(
         jnp.asarray(valid, jnp.int32).reshape(-1), (b,))
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         in_specs=(P(dp_spec, None, None, None), kv_spec, kv_spec,
                   scale_spec, scale_spec, P(dp_spec)),
-        out_specs=P(dp_spec, None, None, None)
+        out_specs=P(dp_spec, None, None, None), check_vma=False
     )(q, layer_kv["k"], layer_kv["v"],
       ks if quantized else jnp.zeros((), jnp.float32),
       vs if quantized else jnp.zeros((), jnp.float32),
@@ -719,8 +718,8 @@ def _flash_decode_ok(cfg: ModelConfig, q, layer_kv) -> tuple[bool, Any]:
     Requires a sequence-sharded cache to exist at all — a mesh with a
     'model' axis that the kv-head count does NOT divide (head-sharded
     caches keep the plain gather path) and S divisible by the axis."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" not in mesh.axis_names:
         return False, None
     b, sq = q.shape[0], q.shape[1]
     S, hkv = layer_kv["k"].shape[1], layer_kv["k"].shape[2]

@@ -27,7 +27,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core.qlinear import QuantPolicy
-from repro.launch import compat
 from repro.models import common as cm
 
 Params = dict[str, Any]
@@ -155,8 +154,8 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
     p_mean = jnp.mean(probs, axis=0)
     aux = E * jnp.sum(density * p_mean)
 
-    mesh = compat.get_abstract_mesh()
-    tp = "model" if (mesh is not None and "model" in mesh.axis_names
+    mesh = jax.sharding.get_abstract_mesh()
+    tp = "model" if ("model" in mesh.axis_names
                      and E % mesh.shape["model"] == 0) else None
 
     def expert_mats(name):
@@ -214,11 +213,11 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig,
         # keep only expert parallelism (every shard sees all tokens)
         xspec = P(dp, None) if hf.shape[0] % dp_sz == 0 else P(None, None)
         espec = jax.tree.map(lambda _: P("model", None, None), mg)
-        out = compat.shard_map(
+        out = jax.shard_map(
             fn,
             in_specs=(xspec, xspec, xspec, xspec, espec, espec,
                       jax.tree.map(lambda _: P("model", None, None), md)),
-            out_specs=xspec
+            out_specs=xspec, check_vma=False
         )(hq, topi, topv, vm, mg, mu, md)
     y = out.reshape(b, s, d)
     if "shared" in p:

@@ -67,9 +67,10 @@ class QuantHealthSampler:
             name: np.asarray(st.act_absmax, np.float32)
             for name, st in (reference or {}).items()
         } or None
+        # params are an argument, not captured as program constants
         self._tap_fn = jax.jit(
-            lambda toks: model.forward_with_taps(params, cfg, toks,
-                                                 policy=policy)[1])
+            lambda p, toks: model.forward_with_taps(p, cfg, toks,
+                                                    policy=policy)[1])
 
     def due(self, tick: int) -> bool:
         return tick % self.every == 0
@@ -83,7 +84,7 @@ class QuantHealthSampler:
         pad = -(-t // self.bucket) * self.bucket
         toks = np.zeros((1, pad), np.int32)
         toks[0, :t] = ctx
-        taps = self._tap_fn(toks)
+        taps = self._tap_fn(self.params, toks)
         modules: dict[str, dict] = {}
         for name in sorted(taps):
             arr = np.asarray(taps[name], np.float32)

@@ -59,7 +59,38 @@ from repro.resilience.faults import FaultInjected
 from repro.serving.config import EngineConfig, resolve_engine_config
 
 __all__ = ["Request", "EngineConfig", "ServingEngine", "PagedServingEngine",
-           "PerSlotServingEngine"]
+           "PerSlotServingEngine", "CompileError"]
+
+
+class CompileError(RuntimeError):
+    """An engine program failed to lower or compile.  Unlike a fault
+    raised while a program runs, this is never contained by the kernel
+    circuit breaker: a kernel the compiler refuses must surface, not be
+    served on the XLA fallback behind the caller's back."""
+
+
+class _StagedJit:
+    """A ``jax.jit`` function whose first call with each input signature
+    lowers and compiles before anything runs, so that a failure there
+    raises :class:`CompileError` and stays apart from runtime faults.
+    Later calls take jit's own fast path, which reuses the executable
+    compiled here."""
+
+    def __init__(self, fn):
+        self._fn = fn
+        self._compiled: set = set()
+
+    def __call__(self, *args):
+        leaves, tree = jax.tree.flatten(args)
+        key = (tree, tuple(jax.typeof(x) for x in leaves))
+        if key not in self._compiled:
+            try:
+                self._fn.lower(*args).compile()
+            except Exception as exc:  # noqa: BLE001 — any lowering failure
+                raise CompileError(f"{exc.__class__.__name__}: {exc}"
+                                   ) from exc
+            self._compiled.add(key)
+        return self._fn(*args)
 
 
 @dataclasses.dataclass
@@ -77,9 +108,10 @@ class Request:
 @functools.lru_cache(maxsize=None)
 def _jitted(model, cfg: ModelConfig, policy: QuantPolicy | None):
     """Process-wide (model, cfg, policy) → jitted (prefill, decode_step)."""
-    prefill = jax.jit(lambda p, t, c: model.prefill(p, cfg, t, c, policy=policy))
-    decode = jax.jit(lambda p, t, c: model.decode_step(p, cfg, t, c,
-                                                       policy=policy))
+    prefill = _StagedJit(jax.jit(
+        lambda p, t, c: model.prefill(p, cfg, t, c, policy=policy)))
+    decode = _StagedJit(jax.jit(
+        lambda p, t, c: model.decode_step(p, cfg, t, c, policy=policy)))
     return prefill, decode
 
 
@@ -87,10 +119,10 @@ def _jitted(model, cfg: ModelConfig, policy: QuantPolicy | None):
 def _jitted_paged_prefill(model, cfg: ModelConfig, policy: QuantPolicy | None):
     """Process-wide jitted in-engine batched prefill; the engine cache is
     donated (the pool is the engine's largest buffer — no tick-time copy)."""
-    return jax.jit(
+    return _StagedJit(jax.jit(
         lambda p, t, ln, c, s: model.prefill_paged(p, cfg, t, ln, c, s,
                                                    policy=policy),
-        donate_argnums=3)
+        donate_argnums=3))
 
 
 @functools.lru_cache(maxsize=None)
@@ -101,10 +133,10 @@ def _jitted_chunked_prefill(model, cfg: ModelConfig,
     over its already-written pool prefix (docs/serving.md).  Only model
     families with ``supports_chunked_prefill`` expose the ``start``
     parameter; the cache is donated exactly like the whole-prompt path."""
-    return jax.jit(
+    return _StagedJit(jax.jit(
         lambda p, t, ln, st, c, s: model.prefill_paged(
             p, cfg, t, ln, c, s, policy=policy, start=st),
-        donate_argnums=4)
+        donate_argnums=4))
 
 
 @functools.lru_cache(maxsize=None)
@@ -115,10 +147,10 @@ def _jitted_verify(model, cfg: ModelConfig, policy: QuantPolicy | None):
     logits come back for ALL positions so greedy acceptance can compare
     the target's argmax against every draft.  The cache is donated like
     the prefill paths (closures re-materialize host state on retry)."""
-    return jax.jit(
+    return _StagedJit(jax.jit(
         lambda p, t, ln, st, c, s: model.verify_paged(
             p, cfg, t, ln, c, s, st, policy=policy),
-        donate_argnums=4)
+        donate_argnums=4))
 
 
 # copy-on-write page clone: one donated jit per pool-leaf shape copies a
@@ -390,11 +422,12 @@ class _EngineBase:
 
         With no fallback (bf16 engines, ``use_kernels="never"``, auto on
         a non-TPU host) a failure propagates: containment moves up to
-        the front-end watchdog.  Otherwise a native failure trips the
-        breaker for ``op`` and the tick completes on the XLA fallback
+        the front-end watchdog.  Otherwise a native runtime failure trips
+        the breaker for ``op`` and the tick completes on the XLA fallback
         jit; while the circuit is open every dispatch rides the fallback
         (counted under ``dispatch.fallback.*``) until a half-open probe
-        succeeds and closes it again.
+        succeeds and closes it again.  A :class:`CompileError` (the
+        native program failed to lower or compile) always propagates.
         """
         if fallback is None:
             if self._faults is not None and self._fire("dispatch_raise",
@@ -406,17 +439,18 @@ class _EngineBase:
         mode = ops.resolve_backend(self.policy.use_kernels, op=op)
         if mode == "xla":
             # circuit open: ride the fallback until the breaker re-probes
+            # (no native call is made, so the dispatch_raise site, which
+            # stands for a native failure, is not reached)
             self._metrics.counter(f"dispatch.fallback.{op}").inc()
-            if self._faults is not None and self._fire("dispatch_raise",
-                                                       op=op):
-                raise FaultInjected("dispatch_raise", f"{op} (fallback)")
             return fallback(), "xla"
         try:
             if self._faults is not None and self._fire("dispatch_raise",
                                                        op=op):
                 raise FaultInjected("dispatch_raise", op)
             out = native()
-        except Exception as exc:  # noqa: BLE001 — any dispatch failure trips
+        except CompileError:
+            raise   # a kernel the compiler refuses is a bug, not a fault
+        except Exception as exc:  # noqa: BLE001 — any runtime failure trips
             ops.breaker.record_failure(op)
             self._metrics.counter("engine.breaker_trips").inc()
             self._metrics.counter(f"dispatch.fallback.{op}").inc()

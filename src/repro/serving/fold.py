@@ -65,11 +65,14 @@ def collect_calibration(model, params, cfg: ModelConfig, batches,
     ``keep_samples > 0`` also retains that many raw activation tokens per
     module per layer (CalibStats.act_samples) for the autoplan search.
     """
+    # params are an argument, not a closure: a captured pytree would be
+    # embedded in the program as constants (gigabytes at full width)
     tap_fn = jax.jit(
-        lambda toks=None, embeds=None: model.forward_with_taps(
-            params, cfg, toks, embeds=embeds)[1])
+        lambda p, toks=None, embeds=None: model.forward_with_taps(
+            p, cfg, toks, embeds=embeds)[1])
     return collect_stats(
-        lambda batch: tap_fn(batch.get("tokens"), batch.get("embeds")),
+        lambda batch: tap_fn(params, batch.get("tokens"),
+                             batch.get("embeds")),
         batches, keep_samples)
 
 
@@ -100,33 +103,35 @@ def _fold_stacked(w: jax.Array, kind: TransformKind,
                   act_absmax: jax.Array | None, *, alpha: float,
                   policy: QuantPolicy, bias: jax.Array | None = None) -> Params:
     """Fold a (L, c_in, c_out) or (L, E, c_in, c_out) stacked linear.
-    Returns the params leaf {"qw": QuantizedWeight[, "b": bias]}."""
-    fn = functools.partial(_fold_one, kind=kind, alpha=alpha, policy=policy)
+    Returns the params leaf {"qw": QuantizedWeight[, "b": bias]}.
+
+    The layer axis is folded one layer at a time (any expert axis is
+    vmapped): the fold's f32 temporaries then stay one layer's size
+    instead of several copies of the whole stack, which at full width
+    (stablelm-3b's gate_proj stack is 2.1 GiB in f32) do not fit in a
+    16 GB chip beside the bf16 params."""
+    def fn(wl, al):
+        return _fold_one(wl, kind, al, alpha=alpha, policy=policy)
+
     n_lead = w.ndim - 2
-    for _ in range(n_lead):
+    for _ in range(n_lead - 1):
         fn = jax.vmap(fn)
-    if act_absmax is None:
-        qw = fn(w, act_absmax=None) if n_lead == 0 else _vmap_nostat(
-            w, kind, alpha, policy, n_lead)
-    else:
-        am = act_absmax
+    am = act_absmax
+    if am is not None:
         # broadcast stats over expert axis if weights have one more lead dim
         while am.ndim < n_lead + 1:
             am = jnp.broadcast_to(am[..., None, :],
                                   (*am.shape[:-1], w.shape[am.ndim - 1], am.shape[-1]))
-        qw = fn(w, act_absmax=am)
+    if n_lead == 0:
+        qw = fn(w, am)
+    else:
+        per_layer = [fn(w[i], None if am is None else am[i])
+                     for i in range(w.shape[0])]
+        qw = jax.tree.map(lambda *xs: jnp.stack(xs), *per_layer)
     out: Params = {"qw": qw}
     if bias is not None:
         out["b"] = bias
     return out
-
-
-def _vmap_nostat(w, kind, alpha, policy, n_lead):
-    fn = functools.partial(_fold_one, kind=kind, act_absmax=None, alpha=alpha,
-                           policy=policy)
-    for _ in range(n_lead):
-        fn = jax.vmap(fn)
-    return fn(w)
 
 
 def _stat(stats: dict[str, CalibStats] | None, name: str):

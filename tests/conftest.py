@@ -4,17 +4,17 @@ design (the 512-device forcing is exclusively dryrun.py's, per task spec).
 
 import jax
 import pytest
-from repro.launch import compat
+from repro.launch.mesh import make_test_mesh
 
 
 @pytest.fixture(scope="session")
 def test_mesh():
     """(1,1) mesh with production axis names (shard_map code paths need
     the named axes to exist)."""
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return make_test_mesh()
 
 
 @pytest.fixture(autouse=True)
 def _under_mesh(test_mesh):
-    with compat.set_mesh(test_mesh):
+    with jax.set_mesh(test_mesh):
         yield

@@ -25,8 +25,10 @@ import pytest
 
 from repro.core.hadamard import apply_hadamard
 from repro.core.qlinear import QuantPolicy, qlinear, quantize_weight
+from repro.core.quantizer import pack_int4, unpack_int4
 from repro.kernels import fused_qlinear as fq
 from repro.kernels import ops, ref
+from repro.kernels import quant_matmul as qm
 from repro.serving.engine import ServingEngine
 
 KEY = jax.random.PRNGKey(0)
@@ -88,6 +90,10 @@ def test_parity_matrix(w_bits, packed, smooth, had, had_mask, act_bits):
     (4096, "sylvester-split"),   # H_512 ⊗ H_8: the decode hot-path dim class
     (12, "pure-paley"),          # no fusable trailing factor: XLA rotation
     (24, "block-fallback"),      # grouped H_8 within 3 groups, fully fused
+    # trailing factors under 128 lanes run as kron(I_{128/b}, H_b)
+    (64, "sylvester-sub128"),    # H_64 alone, padded to one 128-lane group
+    (768, "paley-sub128"),       # Paley_12 ⊗ H_64
+    (6912, "down-proj"),         # Paley_108 ⊗ H_64: stablelm-3b's d_ff
 ])
 def test_parity_structured_dims(d, structure):
     m = 32
@@ -98,6 +104,20 @@ def test_parity_structured_dims(d, structure):
     y_xla = qlinear(x, qw, QuantPolicy(use_kernels="never"))
     assert _rel(y_fused, y_ref) < 0.05, structure
     assert _rel(y_fused, y_xla) < 0.06, structure
+
+
+def test_unpack_nibbles_matches_unpack_int4():
+    """The kernel's in-VMEM unpack (int32 shifts) reads every byte value
+    exactly as the XLA path's ``unpack_int4`` does: the packing format
+    is unchanged."""
+    codes = jnp.asarray(np.arange(-8, 8, dtype=np.int8))
+    pairs = jnp.stack(jnp.meshgrid(codes, codes, indexing="ij"), -1)
+    q = pairs.reshape(-1, 2).T.reshape(2, 256)           # (k=2, m=256)
+    packed = jnp.swapaxes(pack_int4(jnp.swapaxes(q, 0, 1)), 0, 1)
+    want = jnp.swapaxes(unpack_int4(jnp.swapaxes(packed, 0, 1)), 0, 1)
+    np.testing.assert_array_equal(np.asarray(qm._unpack_nibbles(packed)),
+                                  np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(q))
 
 
 @pytest.mark.parametrize("had_mask", [0, 1])

@@ -6,13 +6,13 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.launch import compat
 from repro.launch.hlo_analysis import (
     HloMetrics,
     _is_s2_tensor,
     _type_bytes,
     analyze_hlo,
 )
+from repro.launch.mesh import make_test_mesh
 
 
 def _compile(f, *args, in_shardings=None):
@@ -73,9 +73,9 @@ def test_nested_scan_trips():
 
 
 def test_collective_detection_and_wire():
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh()
     # single-device: no collectives expected — the parser must return 0
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = _compile(lambda a: jnp.sum(a),
                             jax.ShapeDtypeStruct((8, 8), jnp.float32))
     m = analyze_hlo(compiled.as_text())

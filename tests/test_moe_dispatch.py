@@ -8,7 +8,6 @@ import pytest
 from _hypothesis_support import given, settings, st
 
 from repro.models.moe import _local_expert_compute
-from repro.launch import compat
 
 KEY = jax.random.PRNGKey(0)
 
@@ -82,17 +81,17 @@ def test_shard_map_path_matches_local(test_mesh):
     x, topi, topv, wg, wu, wd = _setup()
     local = _local_expert_compute(x, topi, topv, wg, wu, wd, n_experts=4,
                                   k=2, capacity_factor=4.0, axis=None)
-    with compat.set_mesh(test_mesh):
+    with jax.set_mesh(test_mesh):
         def fn(x_, ti, tv, g_, u_, d_):
             return _local_expert_compute(x_, ti, tv, g_, u_, d_,
                                          n_experts=4, k=2,
                                          capacity_factor=4.0, axis="model")
-        sharded = jax.jit(compat.shard_map(
+        sharded = jax.jit(jax.shard_map(
             fn,
             in_specs=(P("data", None), P("data", None), P("data", None),
                       P("model", None, None), P("model", None, None),
                       P("model", None, None)),
-            out_specs=P("data", None)
+            out_specs=P("data", None), check_vma=False
         ))(x, topi, topv, wg, wu, wd)
     np.testing.assert_allclose(np.asarray(sharded), np.asarray(local),
                                rtol=1e-4, atol=1e-5)

@@ -481,6 +481,37 @@ def test_quant_health_clip_fraction_with_reference():
     assert all(0.0 <= c <= 1.0 for c in clips)
 
 
+@pytest.mark.parametrize("tap_forward", ["calibration", "quant_health"])
+def test_tap_forward_takes_params_as_argument(tap_forward):
+    """The with-taps forwards of calibration and quant-health sampling
+    pass the params to their jit as an argument.  Closed over, the
+    params are embedded in the program as constants: at stablelm-3b's
+    full width that is 5.3 GB of literals, and compiling it exhausts a
+    TPU host's memory."""
+    import warnings
+
+    from repro.serving.fold import collect_calibration
+
+    cfg, model, params = _setup()
+    nbytes = sum(a.nbytes for a in jax.tree.leaves(params))
+    toks = jax.random.randint(KEY, (2, 16), 0, cfg.vocab_size)
+    prev = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", nbytes // 2)
+    try:
+        with warnings.catch_warnings():
+            warnings.filterwarnings("error", message=".*constants were "
+                                    "captured")
+            if tap_forward == "calibration":
+                stats = collect_calibration(model, params, cfg,
+                                            [{"tokens": toks}])
+                assert stats
+            else:
+                qh = QuantHealthSampler(model, params, cfg, every=1, bucket=8)
+                assert qh.sample(1, 0, np.arange(6) % cfg.vocab_size)
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", prev)
+
+
 def test_quant_health_in_engine_and_summary():
     """--quant-health wiring end to end: due() gates on ticks, events
     land in the trace, the summary aggregates per module."""

@@ -98,6 +98,20 @@ def test_parity_ragged_lengths(hq, hkv, quantized):
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("hq,hkv,d", [(32, 32, 80), (32, 8, 128)],
+                         ids=["stablelm_mha", "gqa8_d128"])
+def test_parity_published_head_geometry(hq, hkv, d, quantized):
+    """Published head counts and widths: each grid step's block holds
+    every kv head of one page (hkv, d whole), and the GQA groups reduce
+    in-kernel — here at hkv=32 with d=80 (not a lane multiple) and at
+    g=4 with d=128."""
+    q, kv, table, lens = _make_case(3, hq, hkv, d, page=8, width=3,
+                                    lengths=[17, 8, 1], quantized=quantized,
+                                    seed=11)
+    _parity(q, kv, table, lens)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
 @pytest.mark.parametrize("length", [4, 5, 8, 9, 16],
                          ids=["at_edge", "past_edge", "at_edge2",
                               "past_edge2", "full"])

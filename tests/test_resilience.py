@@ -37,8 +37,9 @@ from repro.kernels import ops
 from repro.models.api import get_model
 from repro.obs import Observability, QuantHealthSampler, load_trace, summarize
 from repro.resilience.faults import SITES, FaultInjected, FaultPlan, FaultSpec
-from repro.serving.engine import (PagedServingEngine, PerSlotServingEngine,
-                                  Request, ServingEngine)
+from repro.serving.engine import (CompileError, PagedServingEngine,
+                                  PerSlotServingEngine, Request,
+                                  ServingEngine)
 from repro.serving.fold import collect_calibration, fold_quantize
 from tests._hypothesis_support import given, settings, st
 
@@ -339,6 +340,33 @@ def test_breaker_trips_to_xla_and_recovers(clean_breaker):
     assert c["breaker_trips"] == 1 and c["breaker_recoveries"] == 1
     assert ops.dispatch_resolutions()["breaker_fallback"] == 1
     ops.dispatch_resolutions(reset=True)
+
+
+def test_compile_failure_propagates_without_tripping(clean_breaker,
+                                                     monkeypatch):
+    """A native program that fails to lower — the fused qlinear's
+    ``pallas_call`` refused while tracing, the way the TPU lowering
+    refuses an illegal block — raises CompileError out of the engine:
+    no trip, no fallback dispatch, so no XLA tick can hide the fault.
+    Runtime faults still trip and recover
+    (test_breaker_trips_to_xla_and_recovers pins that)."""
+    from repro.kernels import fused_qlinear as fq
+
+    def refuse(*a, **k):
+        raise ValueError("block shape refused by the TPU lowering")
+
+    monkeypatch.setattr(fq, "_pallas_call", refuse)
+    obs = Observability()
+    # a max_len no other test uses: its programs are not yet compiled
+    eng = _engine(use_kernels="interpret", obs=obs, max_len=28)
+    for r in _reqs(2):
+        eng.submit(r)
+    with pytest.raises(CompileError, match="refused by the TPU lowering"):
+        eng.run(max_ticks=50)
+    assert clean_breaker.state() == {}
+    disp = eng.stats()["dispatch_backends"]
+    assert not any(k.startswith("fallback.") for k in disp)
+    assert obs.summary()["counts"]["breaker_trips"] == 0
 
 
 def test_dispatch_raise_without_fallback_propagates():

@@ -11,6 +11,7 @@ import pytest
 from repro.checkpoint import Checkpointer, restore_pytree, save_pytree
 from repro.configs.base import get_config
 from repro.data import TokenFileDataset, calibration_stream, synthetic_batches
+from repro.launch.mesh import make_mesh, make_test_mesh
 from repro.optim import (
     adamw,
     apply_error_feedback,
@@ -19,7 +20,6 @@ from repro.optim import (
     warmup_cosine,
     warmup_linear,
 )
-from repro.launch import compat
 from repro.runtime.fault_tolerance import (
     Heartbeat,
     PreemptionHandler,
@@ -81,15 +81,15 @@ def test_compressed_psum_inside_shard_map():
 
     from repro.optim import compressed_psum
 
-    mesh = compat.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     g = jax.random.normal(KEY, (1, 128)) * 0.01
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         def f(gl):
             return compressed_psum({"g": gl[0]}, jax.random.PRNGKey(1),
                                    axis="pod")["g"]
-        out = jax.jit(compat.shard_map(f, in_specs=(P("pod", None),),
-                                    out_specs=P()))(g)
+        out = jax.jit(jax.shard_map(f, in_specs=(P("pod", None),),
+                                    out_specs=P(), check_vma=False))(g)
     expected = np.asarray(g.sum(0))
     got = np.asarray(out)
     step = np.abs(np.asarray(g)).max() / 127
@@ -132,7 +132,7 @@ def test_elastic_restore_under_different_sharding(tmp_path):
     """Checkpoints are mesh-agnostic: restore into any target sharding."""
     tree = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     save_pytree(tree, str(tmp_path / "ck"))
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh()
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     template = {"w": jax.device_put(jnp.zeros((4, 4)),

@@ -14,7 +14,6 @@ from repro.launch.sharding import batch_spec, param_specs
 from repro.launch.train import make_train_step, shard_train_fns
 from repro.models.api import get_model
 from repro.optim import adamw
-from repro.launch import compat
 
 KEY = jax.random.PRNGKey(0)
 
@@ -94,7 +93,7 @@ def test_small_mesh_dryrun_lower_compile(test_mesh):
     cfg = get_config("qwen15_4b").reduced()
     model = get_model(cfg)
     opt = adamw(1e-3)
-    with compat.set_mesh(test_mesh):
+    with jax.set_mesh(test_mesh):
         params_shape = jax.eval_shape(lambda k: model.init(k, cfg),
                                       jax.random.PRNGKey(0))
         opt_shape = jax.eval_shape(opt.init, params_shape)
@@ -105,9 +104,8 @@ def test_small_mesh_dryrun_lower_compile(test_mesh):
                  "labels": jax.ShapeDtypeStruct((4, 16), jnp.int32)}
         lowered = jax.jit(
             make_train_step(model, cfg, opt, microbatches=2),
-            in_shardings=compat.jit_shardings(
-                test_mesh, (pspecs, ospecs,
-                            {"tokens": bspec, "labels": bspec}, None, None)),
+            in_shardings=(pspecs, ospecs,
+                          {"tokens": bspec, "labels": bspec}, None, None),
         ).lower(params_shape, opt_shape, batch,
                 jax.ShapeDtypeStruct((), jnp.int32),
                 jax.ShapeDtypeStruct((2,), jnp.uint32))
