@@ -217,5 +217,6 @@ def fused_qlinear(x: jax.Array, qw: QuantizedWeight, *, act_bits: int = 4,
             pltpu.VMEM((bn, 1), jnp.float32),    # per-token Δ_a
         ],
         interpret=interpret,
+        name="fused_qlinear",
     )(*inputs)
     return y[:n, :m_]

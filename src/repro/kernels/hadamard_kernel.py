@@ -95,5 +95,6 @@ def fused_hadamard_quant(x: jax.Array, *, block: int = 128, bits: int = 4,
             jax.ShapeDtypeStruct((n_p, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_hadamard_quant",
     )(x, h)
     return q[:n], s[:n]

@@ -188,5 +188,6 @@ def paged_attention(q: jax.Array, layer_kv: dict, page_table: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(table, lens, *inputs)
     return out.reshape(b, 1, hq, d)

@@ -118,6 +118,7 @@ def _call(kernel, aq, wq, a_scale, w_scale, *, k: int, m: int, n: int,
         out_shape=jax.ShapeDtypeStruct((n, m), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_n, block_m), jnp.int32)],
         interpret=interpret,
+        name="quant_matmul_packed" if packed else "quant_matmul",
     )(aq, wq, a_scale, w_scale)
 
 

@@ -56,5 +56,6 @@ def quantize_per_token(x: jax.Array, *, bits: int = 4, block_n: int = 8,
             jax.ShapeDtypeStruct((n_p, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="quantize_per_token",
     )(x)
     return q[:n], s[:n]

@@ -22,8 +22,7 @@ from repro.launch.hlo_analysis import HloMetrics
 __all__ = ["HW", "RooflineReport", "roofline", "model_params", "model_flops",
            "serving_decode_cell", "serving_tick_flops",
            "serving_prefill_cell", "serving_prefill_flops",
-           "serving_kv_token_elems", "serving_tick_hbm_bytes",
-           "serving_prefill_hbm_bytes"]
+           "serving_kv_token_elems", "serving_prefill_hbm_bytes"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,35 +164,6 @@ def serving_kv_token_elems(cfg: ModelConfig) -> int:
     return 0
 
 
-def serving_tick_hbm_bytes(cfg: ModelConfig, n_slots: int,
-                           mean_context: float, *,
-                           weight_bits: int | None = None,
-                           kv_bits: int | None = None,
-                           backend: str = "xla") -> float:
-    """Modeled HBM bytes of ONE batched decode tick — the quantity the
-    obs layer attributes per kernel backend (docs/observability.md).
-
-    Decode is dominated by two streams, and only those are modeled:
-    the weight stream (active params × storage width — int4 packs two
-    codes per byte) and the KV-cache traffic (read the per-slot context
-    prefix, append one token).  The ``backend`` factor mirrors
-    ``benchmarks.kernel_bench.paged_hbm_bytes``: the XLA ``paged_view``
-    gather fallback materializes a contiguous bf16 view of the context
-    KV (one write + one read) that the in-VMEM Pallas kernel never
-    pays, so "xla" adds two bf16 passes over the read set.  Analytic —
-    a per-backend attribution model, not a measurement.
-    """
-    _, active = model_params(cfg)
-    w_bytes = active * (weight_bits / 8 if weight_bits else 2)
-    elems = serving_kv_token_elems(cfg)
-    kv_elem_bytes = 1 if kv_bits == 8 else 2
-    read = n_slots * mean_context * elems * kv_elem_bytes
-    write = n_slots * elems * kv_elem_bytes
-    gather_extra = (2 * n_slots * mean_context * elems * 2
-                    if backend == "xla" and elems else 0.0)
-    return float(w_bytes + read + write + gather_extra)
-
-
 def serving_prefill_hbm_bytes(cfg: ModelConfig, n_rows: int,
                               padded_len: int, *,
                               weight_bits: int | None = None,
@@ -201,7 +171,9 @@ def serving_prefill_hbm_bytes(cfg: ModelConfig, n_rows: int,
     """Modeled HBM bytes of ONE batched admission dispatch: the weight
     stream plus the KV written for every (row, position) — prefill
     attends from VMEM/registers over its own tile, so no context read
-    term.  Same analytic caveat as :func:`serving_tick_hbm_bytes`."""
+    term.  Analytic (weight stream and KV writes only), not a
+    measurement: the prefix-cache counters price saved admissions in
+    it."""
     _, active = model_params(cfg)
     w_bytes = active * (weight_bits / 8 if weight_bits else 2)
     kv_elem_bytes = 1 if kv_bits == 8 else 2

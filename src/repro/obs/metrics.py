@@ -3,7 +3,7 @@
 A :class:`MetricsRegistry` names three instrument kinds:
 
   * :class:`Counter`   — monotone float (dispatch counts, token totals,
-    modeled HBM bytes per backend);
+    compiles);
   * :class:`Gauge`     — last-write-wins float (pool occupancy);
   * :class:`Histogram` — fixed upper-bound buckets for cheap shape
     inspection PLUS an exact reservoir of every observation, so the

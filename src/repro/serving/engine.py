@@ -33,10 +33,20 @@ tick instead of ``n_active``.
 
 jit caches are shared process-wide per (model, cfg, policy), so
 constructing many engines (property tests, benchmarks) does not retrace.
+
+Every program is a named function, so a profiler trace shows
+``jit_decode_step``, ``jit_prefill_paged``, ``jit_prefill_chunk`` ... and
+not ``jit__lambda``.  The engine opens ``jax.profiler`` spans
+(``engine.step``, ``engine.admit``, ``engine.prefill``, ``engine.decode``,
+``engine.sample`` ...; docs/observability.md has the table) whether or
+not a profiler runs: without one each is an inactive TraceMe costing
+about a microsecond, and with one the host spans land on the same clock
+as the device's program runs.
 """
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import functools
 import time
@@ -51,7 +61,6 @@ from repro.core.qlinear import QuantPolicy
 from repro.launch.roofline import (
     serving_prefill_flops,
     serving_prefill_hbm_bytes,
-    serving_tick_hbm_bytes,
 )
 from repro.models import common as cm
 from repro.obs import MetricsRegistry
@@ -69,12 +78,22 @@ class CompileError(RuntimeError):
     served on the XLA fallback behind the caller's back."""
 
 
+# the ``engine.compiles`` counter of the engine whose step() is running:
+# the jitted programs are shared process-wide, their compiles are counted
+# by the engine that asked for them
+_compile_counter: contextvars.ContextVar = contextvars.ContextVar(
+    "engine_compile_counter", default=None)
+
+_span = jax.profiler.TraceAnnotation
+
+
 class _StagedJit:
     """A ``jax.jit`` function whose first call with each input signature
     lowers and compiles before anything runs, so that a failure there
     raises :class:`CompileError` and stays apart from runtime faults.
     Later calls take jit's own fast path, which reuses the executable
-    compiled here."""
+    compiled here.  Each compile runs in an ``engine.compile`` span and
+    counts once in the stepping engine's ``engine.compiles``."""
 
     def __init__(self, fn):
         self._fn = fn
@@ -84,12 +103,16 @@ class _StagedJit:
         leaves, tree = jax.tree.flatten(args)
         key = (tree, tuple(jax.typeof(x) for x in leaves))
         if key not in self._compiled:
-            try:
-                self._fn.lower(*args).compile()
-            except Exception as exc:  # noqa: BLE001 — any lowering failure
-                raise CompileError(f"{exc.__class__.__name__}: {exc}"
-                                   ) from exc
+            with _span("engine.compile", program=self._fn.__name__):
+                try:
+                    self._fn.lower(*args).compile()
+                except Exception as exc:  # noqa: BLE001 — any lowering failure
+                    raise CompileError(f"{exc.__class__.__name__}: {exc}"
+                                       ) from exc
             self._compiled.add(key)
+            counter = _compile_counter.get()
+            if counter is not None:
+                counter.inc()
         return self._fn(*args)
 
 
@@ -108,21 +131,23 @@ class Request:
 @functools.lru_cache(maxsize=None)
 def _jitted(model, cfg: ModelConfig, policy: QuantPolicy | None):
     """Process-wide (model, cfg, policy) → jitted (prefill, decode_step)."""
-    prefill = _StagedJit(jax.jit(
-        lambda p, t, c: model.prefill(p, cfg, t, c, policy=policy)))
-    decode = _StagedJit(jax.jit(
-        lambda p, t, c: model.decode_step(p, cfg, t, c, policy=policy)))
-    return prefill, decode
+    def prefill(p, t, c):
+        return model.prefill(p, cfg, t, c, policy=policy)
+
+    def decode_step(p, t, c):
+        return model.decode_step(p, cfg, t, c, policy=policy)
+
+    return _StagedJit(jax.jit(prefill)), _StagedJit(jax.jit(decode_step))
 
 
 @functools.lru_cache(maxsize=None)
 def _jitted_paged_prefill(model, cfg: ModelConfig, policy: QuantPolicy | None):
     """Process-wide jitted in-engine batched prefill; the engine cache is
     donated (the pool is the engine's largest buffer — no tick-time copy)."""
-    return _StagedJit(jax.jit(
-        lambda p, t, ln, c, s: model.prefill_paged(p, cfg, t, ln, c, s,
-                                                   policy=policy),
-        donate_argnums=3))
+    def prefill_paged(p, t, ln, c, s):
+        return model.prefill_paged(p, cfg, t, ln, c, s, policy=policy)
+
+    return _StagedJit(jax.jit(prefill_paged, donate_argnums=3))
 
 
 @functools.lru_cache(maxsize=None)
@@ -133,10 +158,11 @@ def _jitted_chunked_prefill(model, cfg: ModelConfig,
     over its already-written pool prefix (docs/serving.md).  Only model
     families with ``supports_chunked_prefill`` expose the ``start``
     parameter; the cache is donated exactly like the whole-prompt path."""
-    return _StagedJit(jax.jit(
-        lambda p, t, ln, st, c, s: model.prefill_paged(
-            p, cfg, t, ln, c, s, policy=policy, start=st),
-        donate_argnums=4))
+    def prefill_chunk(p, t, ln, st, c, s):
+        return model.prefill_paged(p, cfg, t, ln, c, s, policy=policy,
+                                   start=st)
+
+    return _StagedJit(jax.jit(prefill_chunk, donate_argnums=4))
 
 
 @functools.lru_cache(maxsize=None)
@@ -147,16 +173,19 @@ def _jitted_verify(model, cfg: ModelConfig, policy: QuantPolicy | None):
     logits come back for ALL positions so greedy acceptance can compare
     the target's argmax against every draft.  The cache is donated like
     the prefill paths (closures re-materialize host state on retry)."""
-    return _StagedJit(jax.jit(
-        lambda p, t, ln, st, c, s: model.verify_paged(
-            p, cfg, t, ln, c, s, st, policy=policy),
-        donate_argnums=4))
+    def verify_paged(p, t, ln, st, c, s):
+        return model.verify_paged(p, cfg, t, ln, c, s, st, policy=policy)
+
+    return _StagedJit(jax.jit(verify_paged, donate_argnums=4))
 
 
-# copy-on-write page clone: one donated jit per pool-leaf shape copies a
-# single physical page's data inside the pool buffer (page axis 1)
-_page_copy = jax.jit(lambda buf, src, dst: buf.at[:, dst].set(buf[:, src]),
-                     donate_argnums=0)
+def page_copy(buf, src, dst):
+    """Copy one physical page's data inside a pool leaf (page axis 1)."""
+    return buf.at[:, dst].set(buf[:, src])
+
+
+# copy-on-write page clone: one donated jit per pool-leaf shape
+_page_copy = jax.jit(page_copy, donate_argnums=0)
 
 
 def _sample_key(step: int, uid: int) -> jax.Array:
@@ -189,9 +218,10 @@ class _EngineBase:
     always carries one (``run_stats``/``stats()`` read from it), and an
     ``obs=Observability(...)`` argument swaps in a shared registry plus
     the OPT-IN layers: span tracing (submit/admit/prefill/first-token/
-    tick/preempt/retire events + TTFT/queue-wait/tick histograms),
-    per-backend dispatch + modeled-HBM-byte attribution, and
-    quant-health sampling.  With ``obs=None`` the engine takes no
+    tick/preempt/retire events + TTFT/queue-wait/tick histograms, and
+    the shapes of each prefill and decode dispatch) and quant-health
+    sampling.  Per-backend dispatch counts and ``engine.compiles`` are
+    always kept.  With ``obs=None`` the engine takes no
     timestamps, emits no events, and issues exactly the same jitted
     dispatches (tests/test_obs.py pins zero overhead and token
     identity)."""
@@ -224,6 +254,7 @@ class _EngineBase:
         self._c_prefill = self._metrics.counter("engine.prefill_dispatches")
         self._c_ticks = self._metrics.counter("engine.ticks")
         self._c_prefill_tokens = self._metrics.counter("engine.prefill_tokens")
+        self._c_compiles = self._metrics.counter("engine.compiles")
         self._submit_ts: dict[int, float] = {}    # uid → ORIGINAL submit ts
         self._wait_from: dict[int, float] = {}    # uid → submit OR requeue ts
         self._seen_uids: set[int] = set()         # first-token bookkeeping
@@ -566,42 +597,19 @@ class _EngineBase:
         self._metrics.histogram("engine.ttft_s").observe(ttft)
         self._tracer.emit("first_token", ts=now, uid=req.uid, ttft_s=ttft)
 
-    def _attr_decode_dispatch(self, n_rows: int, backend: str | None = None):
-        """Per-backend decode-dispatch count + modeled HBM bytes
-        (launch/roofline.py) — the byte attribution only when obs is on
-        (it walks the active slots for the mean context length).
+    def _attr_dispatch(self, op: str, backend: str | None = None):
+        """Per-backend dispatch count of ``op`` (decode or prefill).
         ``backend`` is the EXECUTED mode when the circuit breaker may
         have rerouted the dispatch (default: the engine's native)."""
         self._metrics.counter(
-            f"dispatch.decode.{backend or self._backend}").inc()
-        if self.obs is None:
-            return
-        ctx = [len(r.prompt) + len(r.out_tokens)
-               for r in self.slots if r is not None]
-        mean_ctx = sum(ctx) / max(len(ctx), 1)
-        pa = getattr(self, "paged_attention_backend", "pallas")
-        nbytes = serving_tick_hbm_bytes(
-            self.cfg, n_rows, mean_ctx,
-            weight_bits=self.policy.weight_bits if self.policy else None,
-            kv_bits=self.kv_bits,
-            backend="xla" if pa == "xla" else "pallas")
-        self._metrics.counter(
-            f"hbm_modeled_bytes.decode.{backend or self._backend}").inc(
-            nbytes)
+            f"dispatch.{op}.{backend or self._backend}").inc()
 
-    def _attr_prefill_dispatch(self, n_rows: int, padded_len: int,
-                               backend: str | None = None):
-        self._metrics.counter(
-            f"dispatch.prefill.{backend or self._backend}").inc()
-        if self.obs is None:
-            return
-        nbytes = serving_prefill_hbm_bytes(
-            self.cfg, n_rows, padded_len,
-            weight_bits=self.policy.weight_bits if self.policy else None,
-            kv_bits=self.kv_bits)
-        self._metrics.counter(
-            f"hbm_modeled_bytes.prefill.{backend or self._backend}").inc(
-            nbytes)
+    def _sample_first(self, logits: jax.Array, req: Request) -> int:
+        """Sample a request's next token from its prefill logits row and
+        read it to the host."""
+        with _span("engine.sample", rows=1):
+            return int(_sample_one(logits, req.temperature, self._step,
+                                   req.uid)[0])
 
     def _maybe_quant_health(self):
         """Opt-in every-N-ticks activation health probe over the active
@@ -652,8 +660,6 @@ class _EngineBase:
             "kernel_backend": self._backend,
             "dispatch_backends": self._metrics.counters_with_prefix(
                 "dispatch."),
-            "hbm_modeled_bytes": self._metrics.counters_with_prefix(
-                "hbm_modeled_bytes."),
             **self._pool_stats(),
         }
 
@@ -677,18 +683,19 @@ class _EngineBase:
                     (lambda t=toks, c=fresh: self._prefill_fb(self.params,
                                                               t, c)))
                 self._c_prefill.inc()
-                self._attr_prefill_dispatch(1, len(ctx), used)
+                self._attr_dispatch("prefill", used)
                 self._count_prefill(req, len(ctx))
-                nxt = int(_sample_one(logits[:, -1], req.temperature,
-                                      self._step, req.uid)[0])
+                nxt = self._sample_first(logits[:, -1], req)
                 if self.obs is not None:
                     # nxt materialized ⇒ the prefill dispatch completed
                     now = self._clock()
                     self._metrics.histogram("engine.prefill_s").observe(
                         now - t0)
-                    self._tracer.emit("prefill", ts=now, n_requests=1,
-                                      n_tokens=len(ctx), rows=1,
-                                      padded_len=len(ctx),
+                    self._tracer.emit("prefill", ts=now, t0=t0,
+                                      n_requests=1, n_tokens=len(ctx),
+                                      rows=1, padded_len=len(ctx),
+                                      padded_tokens=len(ctx),
+                                      lens=[len(ctx)], starts=[0],
                                       dur_s=now - t0)
                     self._obs_prefill_token(req)
                 self._append_token(req, nxt)
@@ -707,6 +714,23 @@ class _EngineBase:
         retain retired requests once handed out)."""
         out, self.retired = self.retired, []
         return out
+
+    def step(self) -> int:
+        """One engine tick; returns the number of rows that decoded.
+        The tick runs in an ``engine.step`` step span (the profiler's
+        step markers), and compiles it triggers count in this engine's
+        ``engine.compiles``."""
+        token = _compile_counter.set(self._c_compiles)
+        try:
+            with jax.profiler.StepTraceAnnotation("engine.step",
+                                                  step_num=self._step + 1):
+                return self._tick()
+        finally:
+            _compile_counter.reset(token)
+
+    def _tick(self) -> int:
+        """The tick itself (scheduling differs per engine)."""
+        raise NotImplementedError
 
     def run(self, max_ticks: int = 1000) -> list[Request]:
         """Tick until queue and slots drain (or the tick budget runs out);
@@ -751,7 +775,7 @@ class ServingEngine(_EngineBase):
 
     # -- one engine tick ----------------------------------------------------
 
-    def step(self) -> int:
+    def _tick(self) -> int:
         """Admit + decode one token for every active slot with a SINGLE
         (max_slots, 1) jitted dispatch. Returns the number of active
         sequences."""
@@ -782,7 +806,7 @@ class ServingEngine(_EngineBase):
             (lambda: self._decode_fb(self.params, last_j, self.cache)))
         self._c_decode.inc()
         self._c_ticks.inc()
-        self._attr_decode_dispatch(self.max_slots, used)
+        self._attr_dispatch("decode", used)
         if self._faults is not None:
             logits = self._poison_logits(logits, active)
         failed = self._guard_rows(logits, active)
@@ -1002,19 +1026,21 @@ class PagedServingEngine(ServingEngine):
     def _host_state_cache(self):
         """Cache pytree with the HOST-authoritative page table + per-slot
         lengths pushed in (stalled/inactive rows never advance)."""
-        c = self.cache
-        if isinstance(c, cm.PagedKVCache):
-            return dataclasses.replace(c, page_table=jnp.asarray(self._pt),
-                                       length=jnp.asarray(self._len))
-        if _paged_part(c) is not None:   # hybrid: paged attn component
-            # NOTE: distinct length buffers — prefill donates the cache,
-            # and one array aliased into two leaves donates twice
-            return dataclasses.replace(
-                c, attn=dataclasses.replace(
-                    c.attn, page_table=jnp.asarray(self._pt),
-                    length=jnp.asarray(np.array(self._len))),
-                length=jnp.asarray(np.array(self._len, copy=True)))
-        return dataclasses.replace(c, length=jnp.asarray(self._len))
+        with _span("engine.host_sync"):
+            c = self.cache
+            if isinstance(c, cm.PagedKVCache):
+                return dataclasses.replace(
+                    c, page_table=jnp.asarray(self._pt),
+                    length=jnp.asarray(self._len))
+            if _paged_part(c) is not None:   # hybrid: paged attn component
+                # NOTE: distinct length buffers — prefill donates the
+                # cache, and one array aliased into two leaves donates twice
+                return dataclasses.replace(
+                    c, attn=dataclasses.replace(
+                        c.attn, page_table=jnp.asarray(self._pt),
+                        length=jnp.asarray(np.array(self._len))),
+                    length=jnp.asarray(np.array(self._len, copy=True)))
+            return dataclasses.replace(c, length=jnp.asarray(self._len))
 
     @property
     def pages_in_use(self) -> int:
@@ -1291,8 +1317,10 @@ class PagedServingEngine(ServingEngine):
         # rounds: a request finishing at prefill frees its slot and pages
         # for the same tick's next round (matches the per-slot oracle's
         # keep-admitting-into-the-slot behaviour)
-        while self._admit_round():
-            pass
+        more = True
+        while more:
+            with _span("engine.admit"):
+                more = self._admit_round()
 
     def _admit_round(self) -> bool:
         free_slots = [i for i in range(self.max_slots)
@@ -1387,46 +1415,44 @@ class PagedServingEngine(ServingEngine):
         s_max = max(len(ctx) for _, _, ctx in batch)
         s_pad = min(self.max_len,
                     -(-s_max // self.prefill_bucket) * self.prefill_bucket)
-        toks = np.zeros((n_pad, s_pad), np.int32)
-        lens = np.zeros((n_pad,), np.int32)
-        rows = np.full((n_pad,), self.max_slots, np.int32)
-        for r, (slot, req, ctx) in enumerate(batch):
-            toks[r, :len(ctx)] = ctx
-            lens[r] = len(ctx)
-            rows[r] = slot
-        if self.obs is not None:
-            t0 = self._clock()
-            for slot, req, _ in batch:
-                self._obs_admitted(req, slot)
-        toks_j, lens_j = jnp.asarray(toks), jnp.asarray(lens)
-        rows_j = jnp.asarray(rows)
-        # the cache is DONATED: each closure materializes its own host-
-        # state pytree, so a breaker retry never touches consumed buffers
-        (logits, self.cache), used = self._dispatch_guarded(
-            "prefill",
-            lambda: self._prefill_paged(self.params, toks_j, lens_j,
-                                        self._host_state_cache(), rows_j),
-            None if self._prefill_paged_fb is None else
-            (lambda: self._prefill_paged_fb(self.params, toks_j, lens_j,
-                                            self._host_state_cache(),
-                                            rows_j)))
+        t0 = self._clock() if self.obs is not None else 0.0
+        with _span("engine.prefill", rows=n_pad,
+                   tokens=sum(len(ctx) for _, _, ctx in batch),
+                   padded=n_pad * s_pad):
+            toks = np.zeros((n_pad, s_pad), np.int32)
+            lens = np.zeros((n_pad,), np.int32)
+            rows = np.full((n_pad,), self.max_slots, np.int32)
+            for r, (slot, req, ctx) in enumerate(batch):
+                toks[r, :len(ctx)] = ctx
+                lens[r] = len(ctx)
+                rows[r] = slot
+            if self.obs is not None:
+                for slot, req, _ in batch:
+                    self._obs_admitted(req, slot)
+            toks_j, lens_j = jnp.asarray(toks), jnp.asarray(lens)
+            rows_j = jnp.asarray(rows)
+            # the cache is DONATED: each closure materializes its own host-
+            # state pytree, so a breaker retry never touches consumed buffers
+            (logits, self.cache), used = self._dispatch_guarded(
+                "prefill",
+                lambda: self._prefill_paged(self.params, toks_j, lens_j,
+                                            self._host_state_cache(), rows_j),
+                None if self._prefill_paged_fb is None else
+                (lambda: self._prefill_paged_fb(self.params, toks_j, lens_j,
+                                                self._host_state_cache(),
+                                                rows_j)))
         self._c_prefill.inc()
-        self._attr_prefill_dispatch(n_pad, s_pad, used)
+        self._attr_dispatch("prefill", used)
         if self.obs is not None:
-            logits.block_until_ready()
-            now = self._clock()
-            self._metrics.histogram("engine.prefill_s").observe(now - t0)
-            self._tracer.emit("prefill", ts=now, n_requests=len(batch),
-                              n_tokens=int(lens.sum()), rows=n_pad,
-                              padded_len=s_pad, dur_s=now - t0)
+            self._obs_prefill(t0, logits, n_pad, s_pad, lens,
+                              np.zeros_like(lens))
         for r, (slot, req, ctx) in enumerate(batch):
             self._count_prefill(req, int(lens[r]))
             # register AFTER the dispatch wrote the pages; BEFORE the
             # finish check, so a one-shot request (the system-prompt
             # seeding shape) still populates the cache as it retires
             self._register_prefix(slot, ctx)
-            nxt = int(_sample_one(logits[r], req.temperature, self._step,
-                                  req.uid)[0])
+            nxt = self._sample_first(logits[r], req)
             if self.obs is not None:
                 self._obs_prefill_token(req)
             self._append_token(req, nxt)
@@ -1443,6 +1469,41 @@ class PagedServingEngine(ServingEngine):
         self._note_occupancy()
         return True
 
+    def _dispatch_cont(self, toks, lens, starts, rows):
+        """ONE continuation prefill dispatch (per-row ``start`` offsets)
+        through the breaker; the cache is donated, so each closure
+        materializes its own host-state pytree.  Returns (logits,
+        executed backend)."""
+        toks_j, lens_j = jnp.asarray(toks), jnp.asarray(lens)
+        starts_j, rows_j = jnp.asarray(starts), jnp.asarray(rows)
+        (logits, self.cache), used = self._dispatch_guarded(
+            "prefill",
+            lambda: self._prefill_cont(self.params, toks_j, lens_j, starts_j,
+                                       self._host_state_cache(), rows_j),
+            None if self._prefill_cont_fb is None else
+            (lambda: self._prefill_cont_fb(self.params, toks_j, lens_j,
+                                           starts_j,
+                                           self._host_state_cache(),
+                                           rows_j)))
+        return logits, used
+
+    def _obs_prefill(self, t0: float, logits, n_pad: int, s_pad: int,
+                     lens: np.ndarray, starts: np.ndarray, **extra):
+        """The ``prefill`` span event of one batched dispatch, once its
+        logits exist: from ``t0`` (before the host arrays were built) to
+        now, with the dispatch's shape and each real row's tokens and
+        start offset."""
+        logits.block_until_ready()
+        now = self._clock()
+        self._metrics.histogram("engine.prefill_s").observe(now - t0)
+        keep = lens > 0
+        self._tracer.emit("prefill", ts=now, t0=t0, n_requests=int(keep.sum()),
+                          n_tokens=int(lens.sum()), rows=n_pad,
+                          padded_len=s_pad, padded_tokens=n_pad * s_pad,
+                          lens=lens[keep].tolist(),
+                          starts=starts[keep].tolist(), dur_s=now - t0,
+                          **extra)
+
     def _prefill_suffix(self, hits: list):
         """ONE batched continuation dispatch for this round's cache-hit
         admissions: each row re-prefills ONLY its non-shared suffix at
@@ -1457,37 +1518,26 @@ class PagedServingEngine(ServingEngine):
         s_max = max(len(ctx) - start for _, _, ctx, start in hits)
         s_pad = min(self.max_len,
                     -(-s_max // self.prefill_bucket) * self.prefill_bucket)
-        toks = np.zeros((n_pad, s_pad), np.int32)
-        lens = np.zeros((n_pad,), np.int32)
-        starts = np.zeros((n_pad,), np.int32)
-        rows = np.full((n_pad,), self.max_slots, np.int32)
-        for r, (slot, req, ctx, start) in enumerate(hits):
-            suffix = ctx[start:]
-            toks[r, :len(suffix)] = suffix
-            lens[r] = len(suffix)
-            starts[r] = start
-            rows[r] = slot
         t0 = self._clock() if self.obs is not None else 0.0
-        toks_j, lens_j = jnp.asarray(toks), jnp.asarray(lens)
-        starts_j, rows_j = jnp.asarray(starts), jnp.asarray(rows)
-        (logits, self.cache), used = self._dispatch_guarded(
-            "prefill",
-            lambda: self._prefill_cont(self.params, toks_j, lens_j, starts_j,
-                                       self._host_state_cache(), rows_j),
-            None if self._prefill_cont_fb is None else
-            (lambda: self._prefill_cont_fb(self.params, toks_j, lens_j,
-                                           starts_j,
-                                           self._host_state_cache(),
-                                           rows_j)))
+        with _span("engine.prefill", rows=n_pad,
+                   tokens=sum(len(ctx) - start for _, _, ctx, start in hits),
+                   padded=n_pad * s_pad):
+            toks = np.zeros((n_pad, s_pad), np.int32)
+            lens = np.zeros((n_pad,), np.int32)
+            starts = np.zeros((n_pad,), np.int32)
+            rows = np.full((n_pad,), self.max_slots, np.int32)
+            for r, (slot, req, ctx, start) in enumerate(hits):
+                suffix = ctx[start:]
+                toks[r, :len(suffix)] = suffix
+                lens[r] = len(suffix)
+                starts[r] = start
+                rows[r] = slot
+            logits, used = self._dispatch_cont(toks, lens, starts, rows)
         self._c_prefill.inc()
-        self._attr_prefill_dispatch(n_pad, s_pad, used)
+        self._attr_dispatch("prefill", used)
         if self.obs is not None:
-            logits.block_until_ready()
-            now = self._clock()
-            self._metrics.histogram("engine.prefill_s").observe(now - t0)
-            self._tracer.emit("prefill", ts=now, n_requests=len(hits),
-                              n_tokens=int(lens.sum()), rows=n_pad,
-                              padded_len=s_pad, dur_s=now - t0, prefix=True)
+            self._obs_prefill(t0, logits, n_pad, s_pad, lens, starts,
+                              prefix=True)
         for r, (slot, req, ctx, start) in enumerate(hits):
             took = int(lens[r])
             self._count_prefill(req, took)
@@ -1495,8 +1545,7 @@ class PagedServingEngine(ServingEngine):
             # deepen the chain: matched entries are skipped, the hit's
             # own full suffix pages register as new descendants
             self._register_prefix(slot, ctx)
-            nxt = int(_sample_one(logits[r], req.temperature, self._step,
-                                  req.uid)[0])
+            nxt = self._sample_first(logits[r], req)
             if self.obs is not None:
                 self._obs_prefill_token(req)
             self._append_token(req, nxt)
@@ -1517,40 +1566,30 @@ class PagedServingEngine(ServingEngine):
         items = sorted(self._prefilling.items())
         chunk = self.prefill_chunk
         n_pad = 1 << (len(items) - 1).bit_length()
-        toks = np.zeros((n_pad, chunk), np.int32)
-        lens = np.zeros((n_pad,), np.int32)
-        starts = np.zeros((n_pad,), np.int32)
-        rows = np.full((n_pad,), self.max_slots, np.int32)
-        for r, (slot, done) in enumerate(items):
-            # resume contexts are stable mid-chunking: a chunk-prefilling
-            # slot sits out decode, so out_tokens cannot grow under it
-            src = self._resume_ctx(self.slots[slot])
-            take = min(chunk, len(src) - done)
-            toks[r, :take] = src[done:done + take]
-            lens[r] = take
-            starts[r] = done
-            rows[r] = slot
         t0 = self._clock() if self.obs is not None else 0.0
-        toks_j, lens_j = jnp.asarray(toks), jnp.asarray(lens)
-        starts_j, rows_j = jnp.asarray(starts), jnp.asarray(rows)
-        (logits, self.cache), used = self._dispatch_guarded(
-            "prefill",
-            lambda: self._prefill_cont(self.params, toks_j, lens_j, starts_j,
-                                       self._host_state_cache(), rows_j),
-            None if self._prefill_cont_fb is None else
-            (lambda: self._prefill_cont_fb(self.params, toks_j, lens_j,
-                                           starts_j,
-                                           self._host_state_cache(),
-                                           rows_j)))
+        # resume contexts are stable mid-chunking: a chunk-prefilling
+        # slot sits out decode, so out_tokens cannot grow under it
+        srcs = [self._resume_ctx(self.slots[slot]) for slot, _ in items]
+        takes = [min(chunk, len(src) - done)
+                 for src, (_, done) in zip(srcs, items)]
+        with _span("engine.prefill_chunk", rows=n_pad, tokens=sum(takes),
+                   padded=n_pad * chunk):
+            toks = np.zeros((n_pad, chunk), np.int32)
+            lens = np.zeros((n_pad,), np.int32)
+            starts = np.zeros((n_pad,), np.int32)
+            rows = np.full((n_pad,), self.max_slots, np.int32)
+            for r, ((slot, done), src, take) in enumerate(
+                    zip(items, srcs, takes)):
+                toks[r, :take] = src[done:done + take]
+                lens[r] = take
+                starts[r] = done
+                rows[r] = slot
+            logits, used = self._dispatch_cont(toks, lens, starts, rows)
         self._c_prefill.inc()
-        self._attr_prefill_dispatch(n_pad, chunk, used)
+        self._attr_dispatch("prefill", used)
         if self.obs is not None:
-            logits.block_until_ready()
-            now = self._clock()
-            self._metrics.histogram("engine.prefill_s").observe(now - t0)
-            self._tracer.emit("prefill", ts=now, n_requests=len(items),
-                              n_tokens=int(lens.sum()), rows=n_pad,
-                              padded_len=chunk, dur_s=now - t0, chunked=True)
+            self._obs_prefill(t0, logits, n_pad, chunk, lens, starts,
+                              chunked=True)
         for r, (slot, done) in enumerate(items):
             req = self.slots[slot]
             took = int(lens[r])
@@ -1564,8 +1603,7 @@ class PagedServingEngine(ServingEngine):
             # cache-hit slot chunking from a matched offset deepens the
             # chain — its matched entries are skipped)
             self._register_prefix(slot, self._resume_ctx(req))
-            nxt = int(_sample_one(logits[r], req.temperature, self._step,
-                                  req.uid)[0])
+            nxt = self._sample_first(logits[r], req)
             if self.obs is not None:
                 self._obs_prefill_token(req)
             self._append_token(req, nxt)
@@ -1604,7 +1642,7 @@ class PagedServingEngine(ServingEngine):
 
     # -- one engine tick ----------------------------------------------------
 
-    def step(self) -> int:
+    def _tick(self) -> int:
         if self._faults is not None:
             self._fire_slow_tick()
         self._admit()
@@ -1618,7 +1656,8 @@ class PagedServingEngine(ServingEngine):
         if not active:
             return 0
         if self._spec_on:
-            return self._spec_step(active)
+            with _span("engine.spec", tick=self._step):
+                return self._spec_step(active)
         t0 = self._clock() if self.obs is not None else 0.0
         # on-demand growth: a slot whose next write starts a new page
         # allocates it now; allocation failure stalls the slot this tick
@@ -1658,27 +1697,28 @@ class PagedServingEngine(ServingEngine):
         if not ready:
             self._preempt_youngest(active)
             return 0
-        last = np.zeros((self.max_slots, 1), np.int32)
-        temps = np.zeros((self.max_slots,), np.float32)
-        uids = np.zeros((self.max_slots,), np.int32)
-        for i in ready:
-            req = self.slots[i]
-            last[i, 0] = req.out_tokens[-1]
-            temps[i] = req.temperature
-            uids[i] = req.uid
         t_alloc = self._clock() if self.obs is not None else 0.0
-        before = self._host_state_cache()
-        last_j = jnp.asarray(last)
-        # decode is NOT donated, so ``before`` stays valid for both the
-        # breaker's fallback retry and the ssm rollback below
-        (logits, self.cache), used = self._dispatch_guarded(
-            "decode",
-            lambda: self._decode(self.params, last_j, before),
-            None if self._decode_fb is None else
-            (lambda: self._decode_fb(self.params, last_j, before)))
+        with _span("engine.decode", rows=len(ready), tick=self._step):
+            last = np.zeros((self.max_slots, 1), np.int32)
+            temps = np.zeros((self.max_slots,), np.float32)
+            uids = np.zeros((self.max_slots,), np.int32)
+            for i in ready:
+                req = self.slots[i]
+                last[i, 0] = req.out_tokens[-1]
+                temps[i] = req.temperature
+                uids[i] = req.uid
+            before = self._host_state_cache()
+            last_j = jnp.asarray(last)
+            # decode is NOT donated, so ``before`` stays valid for both the
+            # breaker's fallback retry and the ssm rollback below
+            (logits, self.cache), used = self._dispatch_guarded(
+                "decode",
+                lambda: self._decode(self.params, last_j, before),
+                None if self._decode_fb is None else
+                (lambda: self._decode_fb(self.params, last_j, before)))
         self._c_decode.inc()
         self._c_ticks.inc()
-        self._attr_decode_dispatch(self.max_slots, used)
+        self._attr_dispatch("decode", used)
         self._metrics.counter(
             f"dispatch.paged_attention.{self.paged_attention_backend}").inc()
         stalled = [i for i in active if i not in ready]
@@ -1694,16 +1734,19 @@ class PagedServingEngine(ServingEngine):
         if self._faults is not None:
             logits = self._poison_logits(logits, ready)
         failed = self._guard_rows(logits, ready)
-        toks = np.asarray(self._sample_batch(logits[:, -1], temps, uids))
+        with _span("engine.sample", rows=len(ready)):
+            toks = np.asarray(self._sample_batch(logits[:, -1], temps, uids))
         if self.obs is not None:
             # toks materialized ⇒ the decode dispatch completed; failed
-            # rows stream no token so they leave the tick's uid list
+            # rows stream no token so they leave the tick's uid list;
+            # ``ctx`` is each live row's context as the dispatch read it
             now = self._clock()
             self._metrics.histogram("engine.tick_s").observe(now - t0)
             self._tracer.emit("tick", ts=now, tick=self.ticks,
                               n_active=len(ready),
                               uids=[self.slots[i].uid for i in ready
                                     if i not in failed],
+                              ctx=[int(self._len[i]) for i in active],
                               n_stalled=len(stalled), dur_s=now - t0,
                               alloc_dur_s=t_alloc - t0)
         for i in ready:
@@ -1842,38 +1885,39 @@ class PagedServingEngine(ServingEngine):
         # Row i scores [out[-1], d_1 .. d_k_i] at start _len[i]; stalled
         # and empty rows ride as sentinels (slot id max_slots → writes
         # drop), exactly like batched-prefill padding rows.
-        W = self.config.spec_k + 1
-        toks = np.zeros((self.max_slots, W), np.int32)
-        lens = np.zeros((self.max_slots,), np.int32)
-        starts = np.zeros((self.max_slots,), np.int32)
-        rows = np.full((self.max_slots,), self.max_slots, np.int32)
-        temps = np.zeros((self.max_slots,), np.float32)
-        uids = np.zeros((self.max_slots,), np.int32)
-        for i in ready:
-            req = self.slots[i]
-            toks[i, 0] = req.out_tokens[-1]
-            toks[i, 1:1 + ks[i]] = drafts[i]
-            lens[i] = 1 + ks[i]
-            starts[i] = self._len[i]
-            rows[i] = i
-            temps[i] = req.temperature
-            uids[i] = req.uid
-        t_alloc = self._clock() if self.obs is not None else 0.0
-        toks_j, lens_j = jnp.asarray(toks), jnp.asarray(lens)
-        starts_j, rows_j = jnp.asarray(starts), jnp.asarray(rows)
-        # the verify jit DONATES the pool (like the prefill paths): each
-        # closure materializes its own host-state pytree so a breaker
-        # retry never touches consumed buffers
-        (logits, self.cache), used = self._dispatch_guarded(
-            "decode",
-            lambda: self._verify(self.params, toks_j, lens_j, starts_j,
-                                 self._host_state_cache(), rows_j),
-            None if self._verify_fb is None else
-            (lambda: self._verify_fb(self.params, toks_j, lens_j, starts_j,
-                                     self._host_state_cache(), rows_j)))
+        with _span("engine.verify", rows=len(ready), tick=self._step):
+            W = self.config.spec_k + 1
+            toks = np.zeros((self.max_slots, W), np.int32)
+            lens = np.zeros((self.max_slots,), np.int32)
+            starts = np.zeros((self.max_slots,), np.int32)
+            rows = np.full((self.max_slots,), self.max_slots, np.int32)
+            temps = np.zeros((self.max_slots,), np.float32)
+            uids = np.zeros((self.max_slots,), np.int32)
+            for i in ready:
+                req = self.slots[i]
+                toks[i, 0] = req.out_tokens[-1]
+                toks[i, 1:1 + ks[i]] = drafts[i]
+                lens[i] = 1 + ks[i]
+                starts[i] = self._len[i]
+                rows[i] = i
+                temps[i] = req.temperature
+                uids[i] = req.uid
+            t_alloc = self._clock() if self.obs is not None else 0.0
+            toks_j, lens_j = jnp.asarray(toks), jnp.asarray(lens)
+            starts_j, rows_j = jnp.asarray(starts), jnp.asarray(rows)
+            # the verify jit DONATES the pool (like the prefill paths): each
+            # closure materializes its own host-state pytree so a breaker
+            # retry never touches consumed buffers
+            (logits, self.cache), used = self._dispatch_guarded(
+                "decode",
+                lambda: self._verify(self.params, toks_j, lens_j, starts_j,
+                                     self._host_state_cache(), rows_j),
+                None if self._verify_fb is None else
+                (lambda: self._verify_fb(self.params, toks_j, lens_j, starts_j,
+                                         self._host_state_cache(), rows_j)))
         self._c_decode.inc()
         self._c_ticks.inc()
-        self._attr_decode_dispatch(self.max_slots, used)
+        self._attr_dispatch("decode", used)
         self._metrics.counter(
             f"dispatch.paged_attention.{self.paged_attention_backend}").inc()
         self._metrics.counter("spec.verify_dispatches").inc()
@@ -1886,9 +1930,10 @@ class PagedServingEngine(ServingEngine):
             lg = np.asarray(logits, np.float32)
             failed = [i for i in ready
                       if not np.isfinite(lg[i, :int(lens[i])]).all()]
-        greedy = np.asarray(jnp.argmax(logits, -1))
-        samp = (np.asarray(self._sample_batch(logits[:, 0], temps, uids))
-                if (temps > 0).any() else None)
+        with _span("engine.sample", rows=len(ready)):
+            greedy = np.asarray(jnp.argmax(logits, -1))
+            samp = (np.asarray(self._sample_batch(logits[:, 0], temps, uids))
+                    if (temps > 0).any() else None)
         now = 0.0
         if self.obs is not None:
             now = self._clock()
@@ -1897,6 +1942,7 @@ class PagedServingEngine(ServingEngine):
                               n_active=len(ready),
                               uids=[self.slots[i].uid for i in ready
                                     if i not in failed],
+                              ctx=[int(self._len[i]) for i in active],
                               n_stalled=len(active) - len(ready),
                               dur_s=now - t0, alloc_dur_s=t_alloc - t0)
         n_drafted = n_accepted = n_emitted_total = 0
@@ -1970,7 +2016,7 @@ class PerSlotServingEngine(_EngineBase):
     def _install_slot_cache(self, slot: int, cache):
         self.caches[slot] = cache
 
-    def step(self) -> int:
+    def _tick(self) -> int:
         if self._faults is not None:
             self._fire_slow_tick()
         self._admit()
@@ -1991,7 +2037,7 @@ class PerSlotServingEngine(_EngineBase):
                 (lambda t=tok, c=self.caches[i]: self._decode_fb(
                     self.params, t, c)))
             self._c_decode.inc()
-            self._attr_decode_dispatch(1, used)
+            self._attr_dispatch("decode", used)
             if (self._faults is not None
                     and self._fire("nan_logits", uid=req.uid,
                                    tick=self._step)):

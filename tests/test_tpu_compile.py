@@ -13,6 +13,7 @@ library, and the test runner's workers all import every test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -102,3 +103,41 @@ def test_paged_attention_compiles(one_chip, kv_dtype, hq, hkv, d):
         jax.ShapeDtypeStruct((b, width), jnp.int32),
         jax.ShapeDtypeStruct((b,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def _named_cases():
+    """One call of each serving kernel at stablelm-3b's widths."""
+    qw = QuantizedWeight(
+        w_q=jax.ShapeDtypeStruct((D_MODEL // 2, D_MODEL), jnp.int8),
+        scale=jax.ShapeDtypeStruct((1, D_MODEL), jnp.float32),
+        smooth=None, bits=4, packed=True, had_dim=D_MODEL)
+    pool = (16, 64, 32, 80)
+    kv = {"k": jax.ShapeDtypeStruct(pool, jnp.int8),
+          "v": jax.ShapeDtypeStruct(pool, jnp.int8),
+          "k_scale": jax.ShapeDtypeStruct((*pool[:3], 1), jnp.float32),
+          "v_scale": jax.ShapeDtypeStruct((*pool[:3], 1), jnp.float32)}
+    return {
+        "fused_qlinear": (fused_qlinear, (
+            jax.ShapeDtypeStruct((32, D_MODEL), jnp.bfloat16), qw)),
+        "paged_attention": (paged_attention, (
+            jax.ShapeDtypeStruct((4, 1, 32, 80), jnp.bfloat16), kv,
+            jax.ShapeDtypeStruct((4, 4), jnp.int32),
+            jax.ShapeDtypeStruct((4,), jnp.int32))),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["fused_qlinear", "paged_attention"])
+def test_kernels_carry_their_names(one_chip, kernel):
+    """A profiler trace finds each kernel by its name: the custom call
+    carries ``kernel_name``, and the compiled instruction (the name of
+    the kernel's op in the trace) is named after it."""
+    sharding, mesh = one_chip
+    fn, args = _named_cases()[kernel]
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        args)
+    with jax.set_mesh(mesh):
+        lowered = jax.jit(fn).lower(*args)
+        compiled = lowered.compile().as_text()
+    assert f'kernel_name = "{kernel}"' in lowered.as_text()
+    assert re.search(rf"%{kernel}(\.\d+)? = \S+ custom-call\(", compiled)
