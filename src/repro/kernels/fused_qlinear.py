@@ -37,10 +37,12 @@ fuse the trailing power-of-two factor; pure-Paley trailing factors
 way there is exactly ONE ``pallas_call`` per quantized linear
 (docs/kernels.md has the full accounting table).
 
-Decode-shaped inputs — the serving engine's ``(max_slots, 1)`` tick
-flattens to n = max_slots rows — are padded up to one (8, k) tile
-instead of degrading to divisor-1 blocks; padded rows quantize to zero
-codes (absmax 0 → Δ = 1) and are sliced off the output.
+The tiles come from the call's shape (``_tiles``): a decode or verify
+call is ONE row block, so each weight tile is fetched from HBM and
+unpacked once per call, and the weight tiles divide the padded dims,
+so the weight is never padded per call.  Rows pad up to whole 8-row
+sublanes; padded rows quantize to zero codes (absmax 0 → Δ = 1) and
+are sliced off the output.
 """
 
 from __future__ import annotations
@@ -60,7 +62,7 @@ from repro.core.hadamard import (
 from repro.core.qlinear import QuantizedWeight
 from repro.core.quantizer import qmax
 from repro.kernels.hadamard_kernel import vmem_rotation_factor
-from repro.kernels.quant_matmul import _round_up, _unpack_nibbles
+from repro.kernels.quant_matmul import _cdiv, _pad2, _round_up, _unpack_nibbles
 
 __all__ = ["fused_qlinear"]
 
@@ -68,6 +70,75 @@ __all__ = ["fused_qlinear"]
 # per qlinear" by wrapping it (fused_qlinear is deliberately NOT wrapped
 # in a module-level jax.jit: callers jit the surrounding model step).
 _pallas_call = pl.pallas_call
+
+# --- tiling (docs/kernels.md "Tiling") ---------------------------------
+# One row block holds every row of a decode or verify call, so each
+# weight tile is fetched from HBM and unpacked once per call; tiles
+# divide the padded dims, so the weight is never padded per call.
+ROW_CAP = 512                # rows per block: a 4 x 256 prefill is 2 blocks
+COL_CAP = 512                # output lanes per block: keeps the (bn, bm)
+                             # accumulator and output small at ROW_CAP rows
+SCOPED_VMEM = 16 << 20       # v5e's default scoped VMEM: no limit below it
+VMEM_CEILING = 96 << 20      # the most a call may ask of v5e's 128 MiB
+ACT_VMEM = 28 << 20          # the (bn, k) activation tile's share
+WEIGHT_VMEM = 8 << 20        # the (bk, bm) weight tile's share
+# Scoped VMEM per element, bounding what Mosaic allocates on v5e
+# (docs/kernels.md has the measurements): a packed int4 weight element
+# takes its DMA buffers plus the unpack's int32 temporaries, an int8
+# one only its buffers; an activation element its buffers, its int8
+# code and the quantize step's f32 temporaries (most without rotation).
+_W_BYTES = {True: 6, False: 2.5}
+_X_BYTES = 8
+
+
+def _aligned_divisors(total: int, unit: int) -> list[int]:
+    """Multiples of ``unit`` that divide ``total`` (itself a multiple)."""
+    q = total // unit
+    return [unit * d for d in range(1, q + 1) if q % d == 0]
+
+
+def _tiles(n: int, k: int, m: int, packed: bool, group: int
+           ) -> tuple[int, int, int]:
+    """(bn, bm, bk) for an (n, k) x (k, m) call.
+
+    bk is a multiple of ``group`` dividing round_up(k, group), bm a
+    multiple of 128 dividing round_up(m, 128) and at most ``COL_CAP``;
+    of those, the pair with the most weight elements whose working set
+    fits ``WEIGHT_VMEM`` (ties to the longer bk: fewer accumulator
+    passes).  bn holds all n rows up to ``ROW_CAP`` (fewer where the
+    activation tile would outgrow ``ACT_VMEM``), split evenly over as
+    few blocks as that allows."""
+    k_p, m_p = _round_up(k, group), _round_up(m, 128)
+    fits = [(bk * bm, bk, bm)
+            for bk in _aligned_divisors(k_p, group)
+            for bm in _aligned_divisors(m_p, 128)
+            if bm <= COL_CAP and bk * bm * _W_BYTES[packed] <= WEIGHT_VMEM]
+    _, bk, bm = max(fits, default=(0, group, 128))
+    cap = max(8, min(ROW_CAP, ACT_VMEM // (k_p * _X_BYTES) // 8 * 8))
+    bn = _round_up(_cdiv(n, _cdiv(n, cap)), 8)
+    return bn, bm, bk
+
+
+def _vmem_bytes(bn: int, bm: int, bk: int, k_p: int, packed: bool,
+                group: int) -> int:
+    """VMEM working set of one grid step, every buffer and temporary
+    counted at its widest (f32 activations and outputs)."""
+    rows = bn * k_p * _X_BYTES                   # activation tile + codes
+    weight = int(bk * bm * _W_BYTES[packed])     # weight tile + unpack
+    out = bn * bm * (2 * 4 + 4 + 4)              # out buffers, acc, dot
+    small = (2 * group * group * 4               # H_b
+             + 2 * 8 * k_p * 4                   # smooth row (8 sublanes)
+             + 2 * 8 * bm * 4                    # weight scales
+             + bn * 128 * 4)                     # per-token scales
+    return rows + weight + out + small
+
+
+def _vmem_limit(nbytes: int) -> int | None:
+    """Scoped-VMEM limit for a step that needs ``nbytes``: the default
+    where it fits, else the footprint with a quarter spare."""
+    if nbytes <= SCOPED_VMEM:
+        return None
+    return min(VMEM_CEILING, _round_up(nbytes * 5 // 4, 1 << 20))
 
 
 def _kernel(*refs, k_steps: int, levels: int, group: int, block_k: int,
@@ -122,8 +193,9 @@ def _kernel(*refs, k_steps: int, levels: int, group: int, block_k: int,
 
 
 def fused_qlinear(x: jax.Array, qw: QuantizedWeight, *, act_bits: int = 4,
-                  interpret: bool = False, block_n: int = 8,
-                  block_m: int = 256, block_k: int = 512) -> jax.Array:
+                  interpret: bool = False, block_n: int | None = None,
+                  block_m: int | None = None,
+                  block_k: int | None = None) -> jax.Array:
     """[smooth] → [online Hadamard] → quantize → int matmul → dequant,
     ONE ``pallas_call``.  x: (n, c_in) float → (n, c_out) in x.dtype.
 
@@ -167,23 +239,25 @@ def fused_qlinear(x: jax.Array, qw: QuantizedWeight, *, act_bits: int = 4,
     has_mask = has_had and had_mask is not None
     levels = qmax(act_bits)
 
-    # --- tiling: pad to tile boundaries instead of degenerate divisors ---
+    # --- tiling: derived from the call's shape (docs/kernels.md) ---
     # the rotation works on rows of ``group`` lanes: H_b itself, or the
     # block-diagonal kron(I_{128/b}, H_b) for factors under a vreg width
     group = max(block, 128)                     # block | group (powers of 2)
-    bn = min(block_n, _round_up(n, 8))
-    bm = min(block_m, _round_up(m_ := qw.c_out, 128))
+    m_ = qw.c_out
+    bn, bm, bk = _tiles(n, k, m_, qw.packed, group)
+    bn = bn if block_n is None else min(block_n, _round_up(n, 8))
+    bm = bm if block_m is None else min(block_m, _round_up(m_, 128))
     # bk must stay a multiple of group: Hadamard groups may not straddle
     # the padded region, and packed nibble pairs may not straddle blocks
     # (group is even) — guards caller-overridden odd/unaligned block_k
-    bk = _round_up(min(block_k, _round_up(k, group)), group)
+    if block_k is not None:
+        bk = _round_up(min(block_k, _round_up(k, group)), group)
     n_p, m_p, k_p = _round_up(n, bn), _round_up(m_, bm), _round_up(k, bk)
+    vmem = _vmem_bytes(bn, bm, bk, k_p, qw.packed, group)
 
-    x_p = jnp.pad(x, ((0, n_p - n), (0, k_p - k)))
-    row_pad = (k_p - k) // 2 if qw.packed else k_p - k
-    w_p = jnp.pad(qw.w_q, ((0, row_pad), (0, m_p - m_)))
-    ws_p = jnp.pad(qw.scale, ((0, 0), (0, m_p - m_)))
-
+    x_p = _pad2(x, n_p, k_p)
+    w_p = _pad2(qw.w_q, k_p // 2 if qw.packed else k_p, m_p)
+    ws_p = _pad2(qw.scale, 1, m_p)
     inputs = [x_p]
     in_specs = [pl.BlockSpec((bn, k_p), lambda i, j, kk: (i, 0))]
     if has_smooth:
@@ -216,6 +290,8 @@ def fused_qlinear(x: jax.Array, qw: QuantizedWeight, *, act_bits: int = 4,
             pltpu.VMEM((bn, k_p), jnp.int8),     # per-row int8 codes
             pltpu.VMEM((bn, 1), jnp.float32),    # per-token Δ_a
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(vmem)),
         interpret=interpret,
         name="fused_qlinear",
     )(*inputs)

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.hadamard import apply_hadamard
+from repro.core.hadamard import apply_hadamard, kernel_fusable_factor
 from repro.core.qlinear import QuantPolicy, qlinear, quantize_weight
 from repro.core.quantizer import pack_int4, unpack_int4
 from repro.kernels import fused_qlinear as fq
@@ -241,3 +241,73 @@ def test_engine_reports_resolved_backend():
     assert eng.kernel_backend == "xla"
     eng.policy = None
     assert eng.kernel_backend == "bf16"
+
+
+# ---------------------------------------------------------------------------
+# tiling: derived from the call's shape
+# ---------------------------------------------------------------------------
+
+# (K, N, had_dim) of stablelm-3b's seven linears and mamba2-780m's two
+_LINEARS = {
+    "q": (2560, 2560, 2560), "k": (2560, 2560, 2560),
+    "v": (2560, 2560, 2560), "o": (2560, 2560, 2560),
+    "gate": (2560, 6912, 2560), "up": (2560, 6912, 2560),
+    "down": (6912, 2560, 6912),
+    "mamba_in": (1536, 6448, 1536), "mamba_out": (3072, 1536, 3072),
+}
+
+
+@pytest.mark.parametrize("n", [1, 8, 32, 160, 256, 1024])
+@pytest.mark.parametrize("packed", [True, False], ids=["w4_packed", "w8"])
+@pytest.mark.parametrize("linear", list(_LINEARS))
+def test_tiles_from_shape(linear, packed, n):
+    """Decode (32 rows) and verify (up to 160) calls are one row block,
+    so each weight tile is fetched and unpacked once per call; the
+    weight tiles divide the padded dims, so the weight is never padded
+    beyond 128 lanes; the step's VMEM fits the limit the kernel sets."""
+    k, m, had_dim = _LINEARS[linear]
+    group = max(kernel_fusable_factor(had_dim), 128)
+    bn, bm, bk = fq._tiles(n, k, m, packed, group)
+    row_blocks = -(-n // bn)
+    assert bn % 8 == 0 and bn <= fq.ROW_CAP
+    if n <= fq.ROW_CAP:
+        assert row_blocks == 1
+    # as few row blocks as the cap allows, split evenly
+    assert row_blocks == -(-n // fq.ROW_CAP)
+    assert row_blocks * bn - n < 8 * row_blocks
+    k_p, m_p = qm._round_up(k, group), qm._round_up(m, 128)
+    assert bk % group == 0 and k_p % bk == 0
+    assert bm % 128 == 0 and m_p % bm == 0 and bm <= fq.COL_CAP
+    nbytes = fq._vmem_bytes(bn, bm, bk, k_p, packed, group)
+    assert nbytes <= (fq._vmem_limit(nbytes) or fq.SCOPED_VMEM)
+    assert (fq._vmem_limit(nbytes) or 0) <= fq.VMEM_CEILING
+
+
+def test_weight_tiles_large_at_cell_widths():
+    """At stablelm-3b's widths a decode call streams each weight once
+    in large tiles, and the int8 weight (no unpack) takes tiles at
+    least as large as the packed one."""
+    for k, m, had_dim in set(_LINEARS.values()):
+        group = max(kernel_fusable_factor(had_dim), 128)
+        _, bm4, bk4 = fq._tiles(32, k, m, True, group)
+        _, bm8, bk8 = fq._tiles(32, k, m, False, group)
+        assert bk4 * bm4 >= 2 ** 18, (k, m)
+        assert bk8 * bm8 >= bk4 * bm4, (k, m)
+
+
+@pytest.mark.parametrize("n", [32, fq.ROW_CAP, fq.ROW_CAP + 88])
+@pytest.mark.parametrize("w_bits,packed", [(4, True), (8, False)],
+                         ids=["w4_packed", "w8"])
+def test_derived_tiles_equal_row_block_8(w_bits, packed, n):
+    """The derived tiles change only how the grid walks the work: int32
+    sums are exact and each row quantizes on its own, so the output
+    equals the 8-row tiling (with 256 x 512 weight tiles) bit for bit,
+    on both sides of the row cap."""
+    d, m = 768, 384                       # Paley_12 x H_64, 3 lane tiles
+    x = jax.random.normal(KEY, (n, d)).astype(jnp.bfloat16)
+    qw = _mk_qw(d, m, w_bits=w_bits, packed=packed, smooth=True, had=True)
+    y = fq.fused_qlinear(x, qw, interpret=True)
+    y8 = fq.fused_qlinear(x, qw, interpret=True, block_n=8, block_m=256,
+                          block_k=512)
+    np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                  np.asarray(y8, np.float32))

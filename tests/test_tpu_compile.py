@@ -67,7 +67,7 @@ def _compiled_text(one_chip, fn, *args) -> str:
         return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("n", [4, 64])
+@pytest.mark.parametrize("n", [4, 32, 64, 1024])
 @pytest.mark.parametrize("k,m,had_dim,smooth", [
     (D_MODEL, D_FF, 0, False),        # gate/up shape, no rotation
     (D_MODEL, D_MODEL, D_MODEL, False),   # Paley_20 ⊗ H_128
