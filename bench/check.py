@@ -7,13 +7,14 @@ those that finished in the window (the longest among them).  The
 reference runs once, teacher-forced over each sampled prompt with its
 served tokens, and gives these numbers:
 
-- ``kv_l<i>`` (attention families): the relative L2 error, over every
-  cached position of the live sample, of the K and V that the timed path
-  wrote into the paged pool at layer i (the larger of the two);
-- ``state_l<i>`` and ``conv_l<i>`` (SSM family): the same for the
-  recurrent state that decode carried to the close, and for its conv
-  tail (the raw in_proj outputs of the last ``conv_kernel - 1``
-  positions);
+- the family's cache numbers (``cache_numbers`` in
+  ``bench/families/<family>.py``), for the layers ``keep_layers`` names:
+  ``kv_l<i>`` (dense) is the relative L2 error, over every cached
+  position of the live sample, of the K and V that the timed path wrote
+  into the paged pool at layer i (the larger of the two); ``state_l<i>``
+  and ``conv_l<i>`` (ssm) the same for the recurrent state that decode
+  carried to the close, and for its conv tail (the raw in_proj outputs
+  of the last ``conv_kernel - 1`` positions);
 - ``gap_p50`` and ``gap_max``: how far below the reference's best logit
   the served token's logit lies, median and widest over every served
   token of the sample;
@@ -44,8 +45,8 @@ import jax
 import numpy as np
 
 from bench import reference as ref
-from bench.serve_cell import calibration_tokens
-from bench.weights import make_weights
+from bench import spec
+from bench.weights import calibration_tokens
 
 N_LIVE = 8
 N_DONE = 8
@@ -56,7 +57,8 @@ def keep_layers(m: dict) -> tuple[int, ...]:
     return tuple(sorted({0, 1, n // 2, n - 1} & set(range(n))))
 
 
-def _rel(a, b) -> float:
+def rel(a, b) -> float:
+    """Relative L2 error of the arrays ``a`` against ``b``, taken whole."""
     a = np.concatenate([np.ravel(x) for x in a]).astype(np.float64)
     b = np.concatenate([np.ravel(x) for x in b]).astype(np.float64)
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
@@ -73,30 +75,14 @@ def _sample_done(run) -> list[tuple[np.ndarray, int]]:
              len(r.prompt)) for r in pick]
 
 
-def _cache_numbers(m, prog, got, layers, tag) -> dict:
-    out = {}
-    for l in layers:
-        if m["family"] == "ssm":
-            out[f"state_l{l}"] = _rel([prog[j][l][0] for j in range(len(got[l]))],
-                                      [g[0] for g in got[l]])
-            out[f"conv_l{l}"] = _rel([prog[j][l][1] for j in range(len(got[l]))],
-                                     [g[1] for g in got[l]])
-        else:
-            k = _rel([prog[j][l][0] for j in range(len(got[l]))],
-                     [g[0] for g in got[l]])
-            v = _rel([prog[j][l][1] for j in range(len(got[l]))],
-                     [g[1] for g in got[l]])
-            out[f"kv_l{l}"] = max(k, v)
-    return {tag + k: v for k, v in out.items()}
-
-
 def numbers(run, control: bool = False) -> dict:
     """Every number the comparison reads; ``control.<name>`` beside
     each with ``control``."""
     m, q = run.m, run.cell.config["quant"]
+    fam = spec.family(m["family"])
     gc.collect()
-    weights = make_weights(m, run.seed)
-    calib = ref.calibrate(weights, m, q, np.concatenate(
+    weights = fam.weights(m, run.seed)
+    calib = fam.calibrate(weights, m, q, np.concatenate(
         calibration_tokens(run.cell.config, run.seed)))
     live = run.live
     done = _sample_done(run)
@@ -118,11 +104,12 @@ def numbers(run, control: bool = False) -> dict:
     res = {}
     if nl:
         sound = {l: out[ref.SOUND]["cache"][l][:nl] for l in layers}
-        res.update(_cache_numbers(m, run.snapshot, sound, layers, ""))
+        res.update(fam.cache_numbers(run.snapshot, sound, layers))
         if control:
             ctrl = {j: {l: out[ref.CONTROL]["cache"][l][j] for l in layers}
                     for j in range(nl)}
-            res.update(_cache_numbers(m, ctrl, sound, layers, "control."))
+            res.update({"control." + k: v for k, v in
+                        fam.cache_numbers(ctrl, sound, layers).items()})
     res["gap_worst_p50"] = max(float(np.median(g)) for g in served if len(g))
     if control:
         res["control.gap_worst_p50"] = max(float(np.median(a))

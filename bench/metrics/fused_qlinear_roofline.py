@@ -12,6 +12,5 @@ def read(run):
     if t <= 0:
         return None
     wb = run.cell.config["quant"]["weight_bits"]
-    ideal = sum(linear_seconds(d.rows * d.seq, run.m, wb, run.peaks)
-                for d in run.dispatches)
+    ideal = sum(linear_seconds(d, run.m, wb, run.peaks) for d in run.dispatches)
     return 100.0 * ideal / t

@@ -12,7 +12,6 @@ def read(run):
     if t <= 0:
         return None
     kv = run.cell.config["quant"]["kv_bits"] or 16
-    ideal = sum(paged_attention_seconds([s + 1 for s in d.starts], run.m, kv,
-                                        run.peaks)
+    ideal = sum(paged_attention_seconds(d, run.m, kv, run.peaks)
                 for d in run.dispatches if d.kind == "decode" and d.starts)
     return 100.0 * ideal / t

@@ -11,8 +11,12 @@ per output channel and activations per token, and a KV cache rounded per
 token and head.  All arithmetic is float32 with matmuls at ``HIGHEST``.
 
 The model runs layer by layer over whole sequences (teacher-forced on
-prompt plus served tokens), so that it fits beside nothing else: after
-the window, with the program's state freed.
+prompt plus served tokens), holding one folded layer at a time, so that
+it fits beside nothing else: after the window, with the program's state
+freed.  What is particular to a model family (its layer, its fold, its
+calibration, and whether sequences run batched) is in
+``bench/families/<family>.py``; this module holds what every family
+shares and the entry points that walk the layers.
 
 ``Precision`` names what the configuration states (bf16 activations,
 int8 KV, float32 SSM state) and the control's step below each of them
@@ -29,6 +33,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import spec
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
@@ -165,162 +171,6 @@ def rms(x: jax.Array, g: jax.Array, eps: float) -> jax.Array:
     return x * inv * g.astype(F32)
 
 
-def _layer(tree, i: int):
-    return jax.tree.map(lambda a: a[i], tree)
-
-
-# ---------------------------------------------------------------------------
-# dense transformer (stablelm family)
-# ---------------------------------------------------------------------------
-
-
-def _rope(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary over the whole head, halves paired: x (T, h, d)."""
-    t, _, d = x.shape
-    half = d // 2
-    freqs = theta ** (-jnp.arange(half, dtype=F32) / half)
-    ang = jnp.arange(t, dtype=F32)[:, None] * freqs
-    c, s = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], -1)
-
-
-def _attend(q, k, v):
-    """Causal attention, q/k/v (T, h, d), kv heads repeated for GQA."""
-    rep = q.shape[1] // k.shape[1]
-    k, v = jnp.repeat(k, rep, 1), jnp.repeat(v, rep, 1)
-    t = q.shape[0]
-    s = jnp.einsum("qhd,khd->hqk", q, k, precision=HIGHEST) * q.shape[-1] ** -0.5
-    s = jnp.where(jnp.tril(jnp.ones((t, t), bool))[None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("hqk,khd->qhd", p, v, precision=HIGHEST)
-
-
-@functools.partial(jax.jit, static_argnames=("m", "q"))
-def _dense_calib_layer(x, lw, *, m, q):
-    """One unquantized float32 layer over calibration rows x (B, T, d);
-    returns the next x and the absmax of the down_proj input."""
-    del q
-    hq, hkv = m["num_attention_heads"], m["num_key_value_heads"]
-    eps, theta = m["norm_eps"], m["rope_theta"]
-    a, f = lw["attn"], lw["mlp"]
-    mm = lambda u, w: jnp.matmul(u, w["w"].astype(F32), precision=HIGHEST)
-
-    def one(xr):
-        t, d = xr.shape
-        h = rms(xr, a["ln"]["g"], eps)
-        qh = _rope(mm(h, a["wq"]).reshape(t, hq, -1), theta)
-        kh = _rope(mm(h, a["wk"]).reshape(t, hkv, -1), theta)
-        vh = mm(h, a["wv"]).reshape(t, hkv, -1)
-        xr = xr + mm(_attend(qh, kh, vh).reshape(t, -1), a["wo"])
-        h = rms(xr, f["ln"]["g"], eps)
-        act = jax.nn.silu(mm(h, f["wg"])) * mm(h, f["wu"])
-        return xr + mm(act, f["wd"]), jnp.max(jnp.abs(act), 0)
-
-    xs, amax = jax.vmap(one)(x)
-    return xs, jnp.max(amax, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("m", "q"))
-def _dense_fold_layer(lw, absmax_down, *, m, q):
-    bits, alpha = q["weight_bits"], q["alpha"]
-    f = lambda w, am=None: fold(w["w"], am, bits=bits, alpha=alpha)
-    a, p = lw["attn"], lw["mlp"]
-    return {"wq": f(a["wq"]), "wk": f(a["wk"]), "wv": f(a["wv"]),
-            "wo": f(a["wo"]), "wg": f(p["wg"]), "wu": f(p["wu"]),
-            "wd": f(p["wd"], absmax_down),
-            "ln1": a["ln"]["g"], "ln2": p["ln"]["g"]}
-
-
-@functools.partial(jax.jit, static_argnames=("m", "q", "p"))
-def _dense_layer(x, fw, *, m, q, p):
-    """One folded layer over one sequence x (T, d); returns the next x
-    and the layer's K and V as the cache holds them (after rotary and
-    rounding)."""
-    t = x.shape[0]
-    hq, hkv = m["num_attention_heads"], m["num_key_value_heads"]
-    eps, theta, ab = m["norm_eps"], m["rope_theta"], q["act_bits"]
-    lin = lambda u, name: qlinear(act_round(u, p), *fw[name], act_bits=ab)
-    h = rms(x, fw["ln1"], eps)
-    qh = _rope(lin(h, "wq").reshape(t, hq, -1), theta)
-    kh = rtn(_rope(lin(h, "wk").reshape(t, hkv, -1), theta), p.kv_bits, -1)
-    vh = rtn(lin(h, "wv").reshape(t, hkv, -1), p.kv_bits, -1)
-    x = act_round(x + lin(_attend(qh, kh, vh).reshape(t, -1), "wo"), p)
-    h = rms(x, fw["ln2"], eps)
-    act = jax.nn.silu(lin(h, "wg")) * lin(h, "wu")
-    x = act_round(x + lin(act, "wd"), p)
-    return x, kh, vh
-
-
-# ---------------------------------------------------------------------------
-# Mamba2 (SSD) family
-# ---------------------------------------------------------------------------
-
-
-def _ssm_sizes(m):
-    di = m["expand"] * m["hidden_size"]
-    gn = m["n_groups"] * m["state_size"]
-    return di, gn, di // m["head_dim"]
-
-
-def _ssm_block(x, lw, proj, m, p, valid):
-    """One Mamba2 block over one sequence x (T, d), the recurrence run
-    token by token; positions at or past ``valid`` leave the state as it
-    is.  ``proj(u, name)`` applies in_proj / out_proj.  Returns the next
-    x and the decode state after ``valid`` tokens: the SSM state
-    (h, p, n) and the conv tail, the last ``conv_kernel - 1`` conv inputs
-    (zeros before the start)."""
-    di, gn, h = _ssm_sizes(m)
-    pd, n, g = m["head_dim"], m["state_size"], m["n_groups"]
-    t = x.shape[0]
-    u = rms(x, lw["ln"]["g"], m["norm_eps"])
-    zxbcdt = proj(u, "in_proj")
-    z, xc = zxbcdt[:, :di], zxbcdt[:, di:2 * di + 2 * gn]
-    dt = zxbcdt[:, 2 * di + 2 * gn:]
-    k = lw["conv_w"].shape[0]
-    pad = jnp.pad(xc, ((k - 1, 0), (0, 0)))
-    conv = sum(pad[i:i + t] * lw["conv_w"][i].astype(F32) for i in range(k))
-    conv = jax.nn.silu(conv + lw["conv_b"].astype(F32))
-    xs = conv[:, :di].reshape(t, h, pd)
-    B = jnp.repeat(conv[:, di:di + gn].reshape(t, g, n), h // g, 1)
-    C = jnp.repeat(conv[:, di + gn:].reshape(t, g, n), h // g, 1)
-    dt = jax.nn.softplus(dt + lw["dt_bias"])
-    dt = jnp.where(jnp.arange(t)[:, None] < valid, dt, 0.0)
-    A = -jnp.exp(lw["A_log"])
-
-    def step(S, inp):
-        x1, b1, c1, dt1 = inp
-        S = state_round(S * jnp.exp(dt1 * A)[:, None, None]
-                        + jnp.einsum("hn,h,hp->hpn", b1, dt1, x1,
-                                     precision=HIGHEST), p)
-        y1 = jnp.einsum("hn,hpn->hp", c1, S, precision=HIGHEST)
-        return S, y1 + x1 * lw["D"][:, None]
-
-    S, y = jax.lax.scan(step, jnp.zeros((h, pd, n), F32), (xs, B, C, dt))
-    y = y.reshape(t, di) * jax.nn.silu(z)
-    y = rms(y, lw["gate_ln"]["g"], m["norm_eps"])
-    idx = valid - (k - 1) + jnp.arange(k - 1)
-    tail = jnp.where((idx >= 0)[:, None], xc[jnp.maximum(idx, 0)], 0.0)
-    return act_round(x + proj(y, "out_proj"), p), (S, tail)
-
-
-@functools.partial(jax.jit, static_argnames=("m", "q"))
-def _ssm_fold_layer(lw, *, m, q):
-    bits, alpha = q["weight_bits"], q["alpha"]
-    out = dict(lw)
-    for name in ("in_proj", "out_proj"):
-        out[name] = fold(lw[name]["w"], None, bits=bits, alpha=alpha)
-    return out
-
-
-@functools.partial(jax.jit, static_argnames=("m", "q", "p"))
-def _ssm_layer(x, fw, valid, *, m, q, p):
-    """One folded Mamba2 layer over a batch of sequences x (B, T, d)."""
-    proj = lambda u, name: qlinear(act_round(u, p), *fw[name],
-                                   act_bits=q["act_bits"])
-    return jax.vmap(lambda xb, vb: _ssm_block(xb, fw, proj, m, p, vb))(x, valid)
-
-
 def _logits(x, g, w, *, m):
     return jnp.matmul(rms(x, g, m["norm_eps"]), w.astype(F32),
                       precision=HIGHEST)
@@ -335,80 +185,51 @@ def _bucket(n: int, b: int = 256) -> int:
     return -(-n // b) * b
 
 
-def calibrate(weights, m: dict, q: dict, calib_tokens: np.ndarray):
-    """Per-layer absmax of the down_proj input over the calibration rows
-    (dense family; the SSM family's plan smooths nothing)."""
-    if m["family"] == "ssm":
-        return None
-    x = jnp.take(weights["embed"]["e"], jnp.asarray(calib_tokens), 0).astype(F32)
-    out = []
-    for i in range(m["num_hidden_layers"]):
-        x, amax = _dense_calib_layer(x, _layer(weights["layers"], i),
-                                     m=_frozen(m), q=_frozen(q))
-        out.append(amax)
-    return out
-
-
-class _Frozen(dict):
+class Frozen(dict):
     """A hashable dict, so a model description can be a static argument."""
 
     def __hash__(self):
         return hash(tuple(sorted(self.items())))
 
 
-def _frozen(d: dict) -> _Frozen:
-    return d if isinstance(d, _Frozen) else _Frozen(d)
+def frozen(d: dict) -> Frozen:
+    return d if isinstance(d, Frozen) else Frozen(d)
+
+
+def embed(weights, tokens) -> jax.Array:
+    return jnp.take(weights.top["embed"]["e"], jnp.asarray(tokens), 0).astype(F32)
 
 
 def forward(weights, m: dict, q: dict, seqs: list[np.ndarray],
             valid: list[int], precisions: tuple[Precision, ...],
             keep_layers: tuple[int, ...], calib_absmax=None):
-    """Teacher-forced forward of ``seqs`` under each precision.
+    """Teacher-forced forward of ``seqs`` under each precision, one
+    folded layer at a time (``weights`` is a ``bench.weights.Weights``).
 
     Returns, per precision, a dict with ``x``: per sequence the final
     hidden states (T_bucket, d), and ``cache``: per kept layer, per
-    sequence, the cache content over the first ``valid`` positions
-    (dense: (K, V) as rounded for the cache; ssm: the SSM state and the
-    conv tail after ``valid`` tokens).
+    sequence, the cache content over the first ``valid`` positions, as
+    the family's ``layer`` gives it.
     """
-    mf, qf = _frozen(m), _frozen(q)
-    ssm = m["family"] == "ssm"
-    # the SSM family runs every sequence at once (its token-by-token
-    # recurrence is long and narrow); the dense family one at a time
-    # (its attention scores are T x T per head)
-    width = max(_bucket(len(s)) for s in seqs)
+    fam = spec.family(m["family"])
+    mf, qf = frozen(m), frozen(q)
     pad = lambda s, n: np.pad(s, (0, n - len(s)))
-    embed = lambda t: jnp.take(weights["embed"]["e"], jnp.asarray(t), 0).astype(F32)
-    if ssm:
-        xs = {p: [embed(np.stack([pad(s, width) for s in seqs]))]
+    if fam.BATCHED:
+        width = max(_bucket(len(s)) for s in seqs)
+        xs = {p: embed(weights, np.stack([pad(s, width) for s in seqs]))
               for p in precisions}
-        vlen = jnp.asarray(valid, jnp.int32)
     else:
-        xs = {p: [embed(pad(s, _bucket(len(s)))) for s in seqs]
+        xs = {p: [embed(weights, pad(s, _bucket(len(s)))) for s in seqs]
               for p in precisions}
     kept = {p: {} for p in precisions}
     for i in range(m["num_hidden_layers"]):
-        lw = _layer(weights["layers"], i)
-        if ssm:
-            fw = _ssm_fold_layer(lw, m=mf, q=qf)
-        else:
-            fw = _dense_fold_layer(lw, calib_absmax[i], m=mf, q=qf)
+        fw = fam.fold_layer(weights.layer(i), i, calib_absmax, mf, qf)
         for p in precisions:
-            if ssm:
-                xs[p][0], (S, tail) = _ssm_layer(xs[p][0], fw, vlen, m=mf,
-                                                 q=qf, p=p)
-                caches = list(zip(S, tail))
-            else:
-                caches = []
-                for j, x in enumerate(xs[p]):
-                    xs[p][j], kh, vh = _dense_layer(x, fw, m=mf, q=qf, p=p)
-                    caches.append((kh[:valid[j]], vh[:valid[j]]))
+            xs[p], caches = fam.layer(xs[p], fw, valid, mf, qf, p)
             if i in keep_layers:
                 kept[p][i] = caches
         del fw
-    if ssm:
-        xs = {p: list(xs[p][0]) for p in precisions}
-    return {p: {"x": xs[p], "cache": kept[p]} for p in precisions}
+    return {p: {"x": list(xs[p]), "cache": kept[p]} for p in precisions}
 
 
 @functools.partial(jax.jit, static_argnames=("m",))
@@ -430,7 +251,7 @@ def gaps(weights, m: dict, x_ref, x_alt, seq: np.ndarray,
     far its reference logit lies below the reference's best, and the
     same for the token the control ranks first at that position."""
     rows = np.arange(n_prompt - 1, len(seq) - 1)
-    served, alt = _gaps(x_ref, weights["final_ln"]["g"],
-                        weights["lm_head"]["w"], jnp.asarray(rows),
-                        jnp.asarray(seq[n_prompt:]), x_alt, m=_frozen(m))
+    served, alt = _gaps(x_ref, weights.top["final_ln"]["g"],
+                        weights.top["lm_head"]["w"], jnp.asarray(rows),
+                        jnp.asarray(seq[n_prompt:]), x_alt, m=frozen(m))
     return np.asarray(served), np.asarray(alt)

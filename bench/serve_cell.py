@@ -1,11 +1,12 @@
 """Build the served model, warm every shape the cell's traffic can
 produce, and drive ``PagedServingEngine`` through the measured window.
 
-The weights are the benchmark's (``bench/weights.py``); calibration and
-the fold are the functions ``serve.build`` calls.  The window drives the
-engine directly in this process: requests are submitted as they fall due
-(open loop) or as a client's last one retires (closed loop), and
-``step()`` runs in a loop.  The engine admits as many requests as it has
+The weights are the benchmark's, made by the configuration's family
+(``bench/families/<family>.py``); calibration and the fold are the
+functions ``serve.build`` calls.  The window drives the engine directly
+in this process: requests are submitted as they fall due (open loop) or
+as a client's last one retires (closed loop), and ``step()`` runs in a
+loop.  The engine admits as many requests as it has
 free slots and pages, so a prefill could take up to ``max_slots`` rows;
 a small front queue in the benchmark hands it at most ``ADMISSION_CAP``
 requests that are not yet decoding (queued or chunk-prefilling), so that
@@ -24,7 +25,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import gc
-import math
 import time
 from collections import deque
 
@@ -33,7 +33,6 @@ import numpy as np
 
 from bench import spec as specmod
 from bench.traffic import RequestSpec, Traffic
-from bench.weights import make_weights
 
 # most requests handed to the engine that are not yet decoding: a limit
 # on the shapes warmed in set-up, not a setting of the deployment
@@ -126,28 +125,21 @@ class _GcTime:
 
 
 def build(cell, seed: int, *, obs: bool):
-    """Make the weights, calibrate, fold, and construct the engine."""
+    """Make the weights, calibrate and fold them (the family's
+    ``served_params``), and construct the engine."""
     from repro.core.qlinear import QuantPolicy
-    from repro.core.transforms import TransformPlan
     from repro.models.api import get_model
     from repro.obs import Observability
     from repro.serving.engine import EngineConfig, PagedServingEngine
-    from repro.serving.fold import collect_calibration, fold_quantize
 
     conf, q, e = cell.config, cell.config["quant"], cell.config["engine"]
     m = specmod.model(conf)
     cfg = specmod.program_config(conf, m)
     model = get_model(cfg)
-    raw = make_weights(m, seed)
-    stats = collect_calibration(
-        model, raw, cfg, [{"tokens": t} for t in calibration_tokens(conf, seed)])
     policy = QuantPolicy(weight_bits=q["weight_bits"], act_bits=q["act_bits"],
                          kv_cache_bits=q["kv_bits"] or None, use_kernels="auto")
-    params = fold_quantize(raw, cfg, policy=policy,
-                           plan=TransformPlan(alpha=q["alpha"]), stats=stats)
-    jax.block_until_ready(params)
-    del raw, stats
-    gc.collect()
+    params = specmod.family(m["family"]).served_params(
+        model, cfg, conf, m, seed, policy)
     eng = PagedServingEngine(model, params, cfg, config=EngineConfig(
         max_slots=e["max_slots"], max_len=e["max_len"], policy=policy,
         kv_bits=q["kv_bits"] or None, page_size=e["page_size"],
@@ -155,13 +147,6 @@ def build(cell, seed: int, *, obs: bool):
         prefill_chunk=e.get("prefill_chunk"),
         obs=Observability() if obs else None))
     return m, cfg, eng
-
-
-def calibration_tokens(conf: dict, seed: int) -> list[np.ndarray]:
-    c = conf["calibration"]
-    rng = np.random.default_rng([seed, 3])
-    return [rng.integers(0, conf["vocab_size"], (c["batch"], c["seq"]))
-            for _ in range(c["batches"])]
 
 
 def shape_set(conf: dict, traffic: dict, chunked: bool) -> list[tuple[int, int]]:
@@ -429,33 +414,18 @@ def _profile_options():
 
 def snapshot(eng, run: Run, keep_layers: tuple[int, ...], n_live: int) -> None:
     """Copy, for a sample of the slots still decoding at the close, the
-    cache the timed path wrote (the layers the check compares), then let
-    the engine go."""
+    cache the timed path wrote (the layers the check compares, as the
+    family's ``snapshot`` reads them), then let the engine go."""
+    fam = specmod.family(run.m["family"])
     live = [(i, r) for i, r in enumerate(eng.slots)
             if r is not None and i not in eng._prefilling and r.out_tokens]
     live.sort(key=lambda ir: -(len(ir[1].prompt) + len(ir[1].out_tokens)))
     rng = np.random.default_rng([run.seed, 4])
     rest = rng.permutation(len(live) - 1)[:n_live - 1] + 1 if live else []
     pick = live[:1] + [live[j] for j in sorted(rest)]
-    cache = eng.cache
     for i, r in pick:
         seq = np.concatenate([np.asarray(r.prompt), np.asarray(r.out_tokens)])
         n = len(seq) - 1                       # the last token is not yet fed
-        if not hasattr(cache, "ssm") and n != int(eng._len[i]):
-            raise RuntimeError(f"slot {i} holds {eng._len[i]} cached "
-                               f"positions, its sequence {n}")
-        per = {}
-        for l in keep_layers:
-            if hasattr(cache, "ssm"):
-                per[l] = (np.asarray(cache.ssm[l, i]),
-                          np.asarray(cache.conv[l, i], np.float32))
-            else:
-                pages = eng._pt[i, :math.ceil(n / eng.page_size)]
-                def read(data, scale):
-                    d = np.asarray(data[l, pages], np.float32)
-                    if scale is not None:
-                        d = d * np.asarray(scale[l, pages], np.float32)
-                    return d.reshape(-1, *d.shape[2:])[:n]
-                per[l] = (read(cache.k, cache.k_scale), read(cache.v, cache.v_scale))
+        per = {l: fam.snapshot(eng, i, l, n) for l in keep_layers}
         run.live.append((seq, len(r.prompt), n))
         run.snapshot[len(run.live) - 1] = per

@@ -11,40 +11,26 @@ traffic; the rest is data under ``bench/``:
   a number or ``None`` when the run holds nothing to read;
 - ``limits/<config>.json``: the limits of the numbers that decide
   ``correct``;
+- ``families/<family>.py``: one module per model family, named by the
+  configuration's ``family``: its keys, weights, reference layer, cache
+  comparison and work counts (``bench/test_families.py`` lists what each
+  provides);
 - ``peaks.json``: the chip's peaks by ``device_kind``.
 
-A later cell, configuration or metric is a new file and a new entry;
-nothing here changes.
+A later cell, configuration, family or metric is a new file and a new
+entry; nothing here changes.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
-
-# model-description keys the reference and the weight maker read, by family
-_MODEL_KEYS = {
-    "dense": ("num_hidden_layers", "hidden_size", "num_attention_heads",
-              "num_key_value_heads", "intermediate_size", "vocab_size",
-              "rope_theta", "norm_eps"),
-    "ssm": ("num_hidden_layers", "hidden_size", "state_size", "head_dim",
-            "expand", "conv_kernel", "n_groups", "vocab_size", "norm_eps"),
-}
-# the same sizes under the program's ModelConfig field names
-_PROGRAM_FIELDS = {
-    "num_hidden_layers": "num_layers", "hidden_size": "d_model",
-    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
-    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "norm_eps": "norm_eps",
-    "state_size": "ssm_state", "head_dim": "ssm_headdim",
-    "expand": "ssm_expand", "conv_kernel": "ssm_conv",
-    "n_groups": "ssm_ngroups",
-}
 
 
 def _json(*parts: str) -> dict:
@@ -107,35 +93,44 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
                    run_seconds=bench["run_seconds"])
 
 
+def _load(kind: str, name: str):
+    path = os.path.join(BENCH, kind, name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def family(name: str):
+    """The module ``families/<name>.py``, loaded once."""
+    return _load("families", name)
+
+
 def model(config: dict) -> dict:
     """The architecture part of a configuration file, as the reference
     and the weight maker read it."""
     fam = config["family"]
-    return {"family": fam, **{k: config[k] for k in _MODEL_KEYS[fam]}}
+    return {"family": fam, **{k: config[k] for k in family(fam).FIELDS}}
 
 
 def program_config(config: dict, m: dict):
     """The program's ModelConfig for this configuration: the preset the
-    file names, with every size the file states set on it (the same
-    values for the benchmark's cells, which ``test_spec`` checks;
-    smaller ones for the CPU tests)."""
+    file names, with every size the file states set on it, and the sizes
+    the family derives from them (the same values for the benchmark's
+    cells, which ``test_spec`` checks; smaller ones for the CPU tests)."""
     from repro.configs.base import get_config
 
-    cfg = get_config(config["model"])
-    want = {_PROGRAM_FIELDS[k]: v for k, v in m.items() if k != "family"}
-    if m["family"] != "ssm":
-        want["head_dim"] = m["hidden_size"] // m["num_attention_heads"]
-    return dataclasses.replace(cfg, **want)
+    fam = family(m["family"])
+    want = {f: m[k] for k, f in fam.FIELDS.items()}
+    want.update(fam.program_fields(m))
+    return dataclasses.replace(get_config(config["model"]), **want)
 
 
 def read_metric(name: str, run) -> float | None:
     """Load ``metrics/<name>.py`` and apply its reader to the run."""
-    path = os.path.join(BENCH, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(run)
+    return _load("metrics", name).read(run)
 
 
 def peaks(device_kind: str) -> dict:

@@ -11,6 +11,7 @@ between chips does not exist in these one-chip serving cells.
 """
 
 import copy
+import os
 import re
 
 import pytest
@@ -20,21 +21,15 @@ from bench import spec
 
 
 def tiny(config: str):
-    """The configuration on ``decode-long`` at two layers and a narrow
-    model, with its limits, and engine knobs and traffic scaled to
-    match."""
+    """The configuration on ``decode-long`` at its family's CPU test
+    sizes (``TINY``: two layers and a narrow model, with engine knobs to
+    match), with its limits, and traffic scaled to match."""
     c = spec.cell_of(config, "decode-long",
                      end_to_end=[{"name": "output_tok_s", "unit": "tokens/s"}])
     conf = copy.deepcopy(c.config)
-    if conf["family"] == "dense":
-        conf.update(num_hidden_layers=2, hidden_size=64, num_attention_heads=4,
-                    num_key_value_heads=4, intermediate_size=128, vocab_size=256)
-        conf["engine"].update(max_slots=4, max_len=160, page_size=16,
-                              n_pages=None, prefill_bucket=16, prefill_chunk=32)
-    else:
-        conf.update(num_hidden_layers=2, hidden_size=128, state_size=32,
-                    head_dim=32, vocab_size=256)
-        conf["engine"].update(max_slots=4, max_len=160, prefill_bucket=32)
+    small = spec.family(conf["family"]).TINY
+    conf.update(small["model"])
+    conf["engine"].update(small["engine"])
     c.config = conf
     c.traffic = copy.deepcopy(c.traffic)
     c.traffic["prompt"].update(median=20, min=8, max=60)
@@ -47,7 +42,10 @@ def _run(name, fault=None, control=False, seed=2**31 + 11):
                               fault=fault, peaks=spec.peaks("TPU v5 lite"))
 
 
-CONFIGS = ["stablelm-3b-w4a4", "mamba2-780m-w4a4"]
+# every configuration file, so that a new family's whole run comes with it
+CONFIGS = sorted(os.path.splitext(f)[0] for f in
+                 os.listdir(os.path.join(spec.BENCH, "configs"))
+                 if f.endswith(".json"))
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -64,7 +62,6 @@ def test_sound_run_passes_and_control_fails(name, capsys):
 def test_unchanged_state_fails(name):
     out = _run(name, fault="state_unchanged")
     assert not out["correct"], out["checks"]
-
 
 
 @pytest.mark.parametrize("fault", ["token_altered", "half_batch",
